@@ -6,7 +6,7 @@ Three probe paths over the same ChainedFilterCascade and key batch:
   per-layer  — ``ChainedFilterCascade.query_jax``: one device dispatch per
                Bloom layer plus an [n, L] stack (the seed implementation);
   fused      — ``cascade_probe``: every layer + the first-zero parity rule
-               in a single Pallas kernel over the packed FilterBank buffer;
+               in one device program over the packed FilterBank buffer;
   service    — ``FilterService.probe`` over a heterogeneous 5-filter bank
                (shared packed buffer, shard_map row dispatch).
 

@@ -49,8 +49,13 @@ REGISTERED_NAMES = frozenset(name for name, _ in REGISTRY)
 
 
 def main() -> int:
+    import pathlib
+
     import jax.numpy as jnp
+    from repro import compile_cache
     from repro.models import common as MC
+    compile_cache.enable(pathlib.Path(__file__).resolve().parents[1]
+                         / ".jax_cache")
     MC.set_compute_dtype(jnp.float32)        # CPU execution dtype
 
     failures = 0

@@ -1,7 +1,7 @@
 """Paper §5.1 (Fig 6 + Fig 7): static dictionary — filter space,
 construction throughput and query throughput of exact Bloomier vs
-ChainedFilter, vs the theoretical lower bound; plus the Pallas probe-kernel
-query path (interpret mode)."""
+ChainedFilter, vs the theoretical lower bound; plus the fused device probe
+query path."""
 from __future__ import annotations
 
 import numpy as np

@@ -3,7 +3,7 @@
 Builds an exact ChainedFilter (Algorithm 1) over 100k keys, verifies
 zero-error membership, compares its size against the single exact Bloomier
 filter and the information-theoretic lower bound, and runs the fused
-two-stage Pallas probe kernel (interpret mode on CPU; Mosaic on TPU).
+two-stage device probe (one jitted XLA program).
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -35,11 +35,11 @@ def main():
     print(f"=> ChainedFilter is {cf.bits / n / lb:.2f}x the bound, "
           f"saves {(1 - cf.bits / eb.bits) * 100:.0f}% vs exact Bloomier")
 
-    # fused two-stage probe kernel (pl.pallas_call, interpret=True on CPU)
+    # fused two-stage device probe
     sample = np.concatenate([pos[:512], neg[:512]])
     got = ops.chained_query(cf, sample)
     assert (got == cf.query(sample)).all()
-    print(f"pallas chained_probe kernel matches oracle on {len(sample)} keys")
+    print(f"fused chained_probe matches oracle on {len(sample)} keys")
 
     # the chain rule itself (Thm 2.2): lossless factorization
     gap = theory.chain_rule_gap(0.001, 64.0, 0.05)

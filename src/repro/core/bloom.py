@@ -1,8 +1,8 @@
 """Bloom filter (Bloom 1970) — elementary approximate filter.
 
 Construction is host-side numpy (scatter-OR); the query path is pure JAX and
-is the oracle for the ``bloom_probe`` Pallas kernel. The bitmap is stored as
-uint32 words so the whole filter sits naturally in VMEM blocks on TPU.
+is the oracle for the fused ``bloom_probe``. The bitmap is stored as uint32
+words, the unit every device probe gathers.
 """
 from __future__ import annotations
 

@@ -8,7 +8,7 @@ there is no 32x32→64 widening multiply either.
 
 Every function has twin implementations: ``numpy`` (host, used for filter
 *construction*) and ``jax.numpy`` (device, used for *query* paths and as the
-reference for the Pallas kernels). Both wrap modulo 2^32 silently.
+reference for the fused device probes). Both wrap modulo 2^32 silently.
 """
 from __future__ import annotations
 

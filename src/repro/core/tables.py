@@ -5,14 +5,15 @@ handful of static integers (sizes, seeds, hash modes). ``to_tables()`` on a
 filter flattens it into a single 128-word-aligned uint32 buffer and a frozen
 *layout descriptor* recording where each sub-table starts (``offset``, in
 words) and the static probe parameters. Descriptors are hashable, so they
-travel through ``jax.jit`` / ``pallas_call`` as static arguments, and they
+travel through ``jax.jit`` as static arguments, and they
 carry enough metadata for ``from_tables()`` to reconstruct a filter object
 with bit-identical query behaviour.
 
 Packing N heterogeneous filters is then pure concatenation: shift each
 layout by the running word cursor (``shift``) and concatenate the buffers.
-The result is ONE VMEM-resident buffer serving every filter — the paper's
-§5.2 "shared address" locality trick lifted from cache lines to VMEM tiles.
+The result is ONE device-resident buffer serving every filter — the
+paper's §5.2 "shared address" locality trick lifted from cache lines to
+one bank.
 """
 from __future__ import annotations
 

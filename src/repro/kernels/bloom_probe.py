@@ -1,13 +1,11 @@
-"""Pallas TPU kernel: batched Bloom-filter probe.
+"""Batched Bloom-filter probe: one jitted XLA program.
 
-The word array lives whole in VMEM (BlockSpec index_map pins it per grid
-step; Mosaic hoists the reload); key lanes stream as (8,128) uint32 tiles.
-All k probes are unrolled — k is small (≤ 16) and static — so the body is
-pure VPU bitwise work plus k vectorized VMEM gathers, no scalar loop.
+All k probes are unrolled — k is small (≤ 16) and static — so the program
+is k word gathers from the device-resident word array plus bitwise lane
+math, with no scalar loop.
 
 ``words`` may be a packed FilterBank buffer (core.tables): the static
-``offset`` selects this filter's word slice, sharing one VMEM residency
-across every filter in the bank.
+``offset`` selects this filter's word slice of the shared buffer.
 """
 from __future__ import annotations
 
@@ -15,38 +13,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from .common import BLOCK_ROWS, BLOCK_COLS, bloom_hit
-
-
-def _kernel(words_ref, hi_ref, lo_ref, out_ref, *, m_bits: int, k: int,
-            seed: int, offset: int):
-    hit = bloom_hit(words_ref[...], hi_ref[...], lo_ref[...],
-                    m_bits=m_bits, k=k, seed=seed, offset=offset)
-    out_ref[...] = hit.astype(jnp.int32)
+from .common import bloom_hit
 
 
-@functools.partial(jax.jit, static_argnames=("m_bits", "k", "seed", "offset",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("m_bits", "k", "seed", "offset"))
 def bloom_probe(words: jnp.ndarray, hi2d: jnp.ndarray, lo2d: jnp.ndarray,
-                *, m_bits: int, k: int, seed: int, offset: int = 0,
-                interpret: bool = True) -> jnp.ndarray:
+                *, m_bits: int, k: int, seed: int, offset: int = 0
+                ) -> jnp.ndarray:
     """words: uint32 [W] (W % 128 == 0); hi2d/lo2d: uint32 [R, 128] with
     R % 8 == 0. Returns int32 [R, 128] (1 = maybe-member)."""
-    R = hi2d.shape[0]
-    grid = (R // BLOCK_ROWS,)
-    W = words.shape[0]
-    return pl.pallas_call(
-        functools.partial(_kernel, m_bits=m_bits, k=k, seed=seed,
-                          offset=offset),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((W,), lambda i: (0,)),                     # table: VMEM-resident
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32),
-        interpret=interpret,
-    )(words, hi2d, lo2d)
+    return bloom_hit(words, hi2d, lo2d, m_bits=m_bits, k=k, seed=seed,
+                     offset=offset).astype(jnp.int32)

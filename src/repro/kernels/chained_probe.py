@@ -1,11 +1,11 @@
-"""Pallas TPU kernel: fused ChainedFilterAnd probe (stage1 ∧ stage2).
+"""Fused ChainedFilterAnd probe (stage1 ∧ stage2): one jitted XLA program.
 
-The CPU reference short-circuits stage 2 for stage-1 rejects; on TPU the
-branch-free fused form is faster: both tables live in ONE packed
-VMEM-resident buffer (core.tables layout, static word offsets), the six
+The CPU reference short-circuits stage 2 for stage-1 rejects; on the device
+the branch-free fused form is faster: both tables live in ONE packed
+device-resident buffer (core.tables layout, static word offsets), the six
 gathers + bitwise reduce cost less than any divergence machinery, and the
-key tile is loaded exactly once (the paper's §5.2 'shared address' locality
-trick, lifted to VMEM tiles).
+key lanes are loaded exactly once (the paper's §5.2 'shared address'
+locality trick).
 
 Outputs both membership and the per-key *sequential probe count*
 (1 + stage-1 pass: a sequential querier touches stage 2 only when stage 1
@@ -17,18 +17,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core import hashing as H
-from .common import BLOCK_ROWS, BLOCK_COLS, xor_lookup
+from .common import xor_lookup
 
 
-def _kernel(tables_ref, hi_ref, lo_ref, member_ref, probes_ref, *,
-            l1: tuple | None, l2: tuple, alpha: int, fp_seed: int,
-            strategy: str, bit_seed: int):
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    tables = tables_ref[...]
+@functools.partial(jax.jit, static_argnames=("l1", "l2", "alpha", "fp_seed",
+                                             "strategy", "bit_seed"))
+def chained_probe(tables, hi2d, lo2d, *, l1: tuple | None, l2: tuple,
+                  alpha: int, fp_seed: int, strategy: str, bit_seed: int):
+    """tables: packed uint32 buffer holding both stages.
+    l1/l2 = (mode, seed, seg_len, n_seg, offset) static layout tuples;
+    l1 may be None (degenerate λ: no stage 1).
+    Returns (member, probes) int32 [R, 128] pairs."""
+    hi, lo = hi2d, lo2d
     if l1 is not None:
         # stage 1: α-bit fingerprint match
         mode1, seed1, seg1, nseg1, off1 = l1
@@ -46,38 +48,9 @@ def _kernel(tables_ref, hi_ref, lo_ref, member_ref, probes_ref, *,
         tgt = H.jx_hash_u32(hi, lo, bit_seed) & jnp.uint32(1)
     else:
         tgt = jnp.uint32(1)
-    member_ref[...] = (s1 & (v2 == tgt)).astype(jnp.int32)
+    member = (s1 & (v2 == tgt)).astype(jnp.int32)
     if l1 is not None:
-        probes_ref[...] = 1 + s1.astype(jnp.int32)
+        probes = 1 + s1.astype(jnp.int32)
     else:
-        probes_ref[...] = jnp.ones(hi.shape, dtype=jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("l1", "l2", "alpha", "fp_seed",
-                                             "strategy", "bit_seed", "interpret"))
-def chained_probe(tables, hi2d, lo2d, *, l1: tuple | None, l2: tuple,
-                  alpha: int, fp_seed: int, strategy: str, bit_seed: int,
-                  interpret: bool = True):
-    """tables: packed uint32 buffer holding both stages.
-    l1/l2 = (mode, seed, seg_len, n_seg, offset) static layout tuples;
-    l1 may be None (degenerate λ: no stage 1).
-    Returns (member, probes) int32 [R, 128] pairs."""
-    R = hi2d.shape[0]
-    W = tables.shape[0]
-    kern = functools.partial(_kernel, l1=l1, l2=l2, alpha=alpha,
-                             fp_seed=fp_seed, strategy=strategy,
-                             bit_seed=bit_seed)
-    tile = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0))
-    return pl.pallas_call(
-        kern,
-        grid=(R // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((W,), lambda i: (0,)),   # packed tables, VMEM-resident
-            tile,
-            tile,
-        ],
-        out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32),
-                   jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32)],
-        interpret=interpret,
-    )(tables, hi2d, lo2d)
+        probes = jnp.ones(hi.shape, dtype=jnp.int32)
+    return member, probes

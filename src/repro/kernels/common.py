@@ -1,13 +1,12 @@
-"""Shared Pallas-kernel plumbing for the filter probe hot path.
+"""Shared plumbing for the filter probe hot path.
 
-Design (DESIGN.md §3): membership filters are small by construction, so the
-whole table is pinned in VMEM (a 1M-key ChainedFilter is ~1.3 MB « 16 MB);
-query keys stream through the grid in (8, 128)-aligned uint32 blocks — the
-natural VPU tile. Probes are vectorized gathers + bitwise ops; there is no
-scalar path at all.
-
-This container has no TPU: ``interpret=True`` executes kernel bodies on CPU
-for correctness; the BlockSpecs below are the real TPU tiling.
+Every probe is one jitted XLA program. The packed FilterBank buffer
+(core.tables) stays in device memory (HBM on a TPU) and each probe gathers
+the few words a key needs from it; query keys travel as (hi, lo) uint32
+lanes padded to whole (8, 128) tiles. A store's bank grows with its key
+count (tens of MiB at 10^7 keys), so it is not pinned in VMEM: the TPU
+compiler refuses a whole-bank VMEM block of 16 MiB, and refuses a vector
+gather from a multi-word VMEM table inside a Pallas kernel.
 """
 from __future__ import annotations
 
@@ -24,11 +23,11 @@ BLOCK = BLOCK_ROWS * BLOCK_COLS
 
 
 # ---------------------------------------------------------------------------
-# packed-table lookups — shared by every probe kernel body
+# packed-table lookups — shared by every probe program
 #
 # All helpers take a word ``offset`` into a packed FilterBank buffer
-# (core.tables), so N heterogeneous filters can live in ONE VMEM-resident
-# uint32 array and each kernel gathers from its own slice. offset=0 recovers
+# (core.tables), so N heterogeneous filters can live in ONE device-resident
+# uint32 array and each probe gathers from its own slice. offset=0 recovers
 # the single-filter case.
 # ---------------------------------------------------------------------------
 

@@ -1,18 +1,19 @@
-"""Pallas TPU kernel: fused multi-SSTable LSM filter probe (paper §5.4).
+"""Fused multi-SSTable LSM filter probe (paper §5.4): one jitted XLA program.
 
 An LSM point query probes every SSTable's filter newest→oldest and — with
 per-table exact ChainedFilters — reads at most ONE table (the first hit;
 Fig 11b). The host model does that per key, per table; here ALL tables'
-filters are evaluated for an (8, 128) key tile inside ONE kernel launch:
-the per-table chain tables (stage-1 Xor slots + stage-2 Othello bitmaps,
+filters are evaluated for the whole key batch in ONE device program: the
+per-table chain tables (stage-1 Xor slots + stage-2 Othello bitmaps,
 packed by core.tables into a single 128-word-aligned uint32 FilterBank
-buffer) are VMEM-resident, each key tile is loaded exactly once per store
-— never per table — and the newest-first first-hit reduction happens in
-registers. This replaces N per-table kernel dispatches with one launch,
-the same §5.2 'shared address' locality trick the cascade kernel applies
-across Bloom layers, applied across SSTables.
+buffer) stay in device memory and are read by gathers, the key lanes are
+loaded exactly once per store — never per table — and the newest-first
+first-hit reduction fuses into the same program. This replaces N
+per-table dispatches with one, the same §5.2 'shared address' locality
+trick the cascade probe applies across Bloom layers, applied across
+SSTables.
 
-Per key the kernel emits:
+Per key the probe emits:
 
 - ``first_hit``  int32 — newest-first index of the first table whose filter
   fires, or N when none does. Under the chain rule this is the ONLY table a
@@ -30,14 +31,13 @@ Per key the kernel emits:
   ('bloom', (m_bits, k, seed, offset))      — per-table Bloom baseline
   ('always',)                               — no filter (always read)
 
-Inside the kernel the per-table loop is NOT a scalar unroll: all 'chain'
-tables sharing a slot-layout mode are evaluated *vectorized across tables*
-— static per-table parameters (hash seeds, segment lengths, table sizes,
-word offsets) become constant [T, 1, 1] lanes broadcast against the
-[8, 128] key tile, so every table's slot indices land in ONE [T, 8, 128]
+The per-table loop is NOT a scalar unroll: all 'chain' tables sharing a
+slot-layout mode are evaluated *vectorized across tables* — per-table
+parameters (hash seeds, segment lengths, table sizes, word offsets) travel
+as [T, 1, 1] lanes of the packed ``params`` input broadcast against the
+[R, 128] key lanes, so every table's slot indices land in ONE [T, R, 128]
 gather from the shared bank buffer and the whole chain stack costs one op
-sweep instead of T. That is what makes the fused launch ~T× cheaper than
-T per-table dispatches rather than merely saving launch overhead.
+sweep instead of T.
 """
 from __future__ import annotations
 
@@ -46,20 +46,19 @@ import functools
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core import hashing as H
 from repro.core.hashing import _GOLDEN
-from .common import BLOCK_ROWS, BLOCK_COLS, bloom_hit, xor_lookup
+from .common import bloom_hit, xor_lookup
 
 MAX_TABLES = 32     # hits_mask is an int32 bitmask
 
 
 # ---------------------------------------------------------------------------
-# table-vectorized hashing: per-table static ints travel as [T, 1, 1] lanes
-# of a small packed uint32 params input (pallas kernels may not capture
-# array constants); every op below must mirror core.hashing bit-for-bit
-# (uint32 wrap).
+# table-vectorized hashing: per-table ints travel as [T, 1, 1] lanes of a
+# small packed uint32 params input (run-time values, so a generation's
+# frozen lanes are what its probes read); every op below must mirror
+# core.hashing bit-for-bit (uint32 wrap).
 # ---------------------------------------------------------------------------
 
 _N_FIELDS = 11   # params rows per chain group, see _group_params
@@ -68,8 +67,8 @@ _N_FIELDS = 11   # params rows per chain group, see _group_params
 def _group_chains(chains: tuple) -> tuple[dict, list]:
     """Partition table indices: vectorizable two-stage chains grouped by
     slot-layout mode, everything else (bloom / always / degenerate chain)
-    on the scalar path. Shared by the wrapper (params packing) and the
-    kernel (params slicing) so field order always agrees."""
+    on the scalar path. Shared by params packing and params slicing so
+    field order always agrees."""
     groups: dict[str, list[int]] = {}
     scalar: list[int] = []
     for t, chain in enumerate(chains):
@@ -240,35 +239,8 @@ def _table_hit(words, hi, lo, chain):
     raise ValueError(f"unknown chain tag {tag!r}")
 
 
-def _kernel(words_ref, params_ref, hi_ref, lo_ref, first_ref, mask_ref, *,
-            chains: tuple):
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    words = words_ref[...]
-    params = params_ref[...]
-    n = len(chains)
-    hits: list = [None] * n
-    groups, scalar = _group_chains(chains)
-    for t in scalar:             # bloom / always / degenerate chain
-        hits[t] = _table_hit(words, hi, lo, chains[t])
-    base = 0
-    for mode, ts in groups.items():
-        g = _grouped_chain_hits(words, params, hi, lo, base, len(ts), mode)
-        for j, t in enumerate(ts):
-            hits[t] = g[j]
-        base += _N_FIELDS * len(ts)
-    stack = jnp.stack(hits)                       # bool [n, R, C]
-    t_lane = jnp.arange(n, dtype=jnp.int32).reshape(-1, 1, 1)
-    mask_ref[...] = (stack.astype(jnp.int32) << t_lane).sum(axis=0)
-    # argmax over the table axis = newest-first first hit (ties → lowest t)
-    first_ref[...] = jnp.where(stack.any(axis=0),
-                               jnp.argmax(stack, axis=0).astype(jnp.int32),
-                               jnp.int32(n))
-
-
-@functools.partial(jax.jit, static_argnames=("chains", "interpret"))
-def lsm_probe(words, hi2d, lo2d, params=None, *, chains: tuple,
-              interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chains",))
+def lsm_probe(words, hi2d, lo2d, params=None, *, chains: tuple):
     """words: packed uint32 FilterBank buffer (W % 128 == 0); hi2d/lo2d:
     uint32 [R, 128] with R % 8 == 0; chains: static per-table descriptors,
     newest first (see module docstring). ``params`` may be a precomputed
@@ -278,72 +250,50 @@ def lsm_probe(words, hi2d, lo2d, params=None, *, chains: tuple,
     int32 [R, 128]."""
     if len(chains) == 0 or len(chains) > MAX_TABLES:
         raise ValueError(f"need 1..{MAX_TABLES} tables, got {len(chains)}")
-    R = hi2d.shape[0]
-    W = words.shape[0]
     if params is None:
         params = pack_chain_params(chains)
     elif params.shape[0] != chain_params_len(chains):
         raise ValueError(
             f"params length {params.shape[0]} does not match chains "
             f"(expected {chain_params_len(chains)})")
-    P = params.shape[0]
-    tile = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel, chains=chains),
-        grid=(R // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((W,), lambda i: (0,)),   # whole bank, VMEM-resident
-            pl.BlockSpec((P,), lambda i: (0,)),   # per-table param lanes
-            tile,
-            tile,
-        ],
-        out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32),
-                   jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32)],
-        interpret=interpret,
-    )(words, jnp.asarray(params), hi2d, lo2d)
+    params = jnp.asarray(params)
+    n = len(chains)
+    hits: list = [None] * n
+    groups, scalar = _group_chains(chains)
+    for t in scalar:             # bloom / always / degenerate chain
+        hits[t] = _table_hit(words, hi2d, lo2d, chains[t])
+    base = 0
+    for mode, ts in groups.items():
+        g = _grouped_chain_hits(words, params, hi2d, lo2d, base, len(ts),
+                                mode)
+        for j, t in enumerate(ts):
+            hits[t] = g[j]
+        base += _N_FIELDS * len(ts)
+    stack = jnp.stack(hits)                       # bool [n, R, C]
+    t_lane = jnp.arange(n, dtype=jnp.int32).reshape(-1, 1, 1)
+    mask = (stack.astype(jnp.int32) << t_lane).sum(axis=0)
+    # argmax over the table axis = newest-first first hit (ties → lowest t)
+    first = jnp.where(stack.any(axis=0),
+                      jnp.argmax(stack, axis=0).astype(jnp.int32),
+                      jnp.int32(n))
+    return first, mask
 
 
-def _kernel_single(words_ref, hi_ref, lo_ref, member_ref, probes_ref, *,
-                   chain: tuple):
-    """One ChainedTableFilter: membership + sequential probe count
-    (1 + stage-1 pass — a sequential querier touches the Othello stage only
-    when stage 1 fires, the paper's Fig 7b accounting)."""
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    words = words_ref[...]
-    _, xor_params, oth_params = chain
-    s1 = _chain_stage1(words, hi, lo, xor_params)
-    ma, mb, seed, off_a, off_b = oth_params
-    s2 = othello_hit(words, hi, lo, ma=ma, mb=mb, seed=seed,
-                     offset_a=off_a, offset_b=off_b)
-    member_ref[...] = (s1 & s2).astype(jnp.int32)
-    if xor_params is None:
-        probes_ref[...] = jnp.ones(hi.shape, dtype=jnp.int32)
-    else:
-        probes_ref[...] = 1 + s1.astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("chain", "interpret"))
-def lsm_chain_probe(words, hi2d, lo2d, *, chain: tuple,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chain",))
+def lsm_chain_probe(words, hi2d, lo2d, *, chain: tuple):
     """Single-filter probe of one LsmChainLayout (the per-table dispatch
     path — what the fused ``lsm_probe`` replaces N of, and the
-    FilterService bank dispatch for LSM chain filters).
-    Returns (member, probes) int32 [R, 128]."""
-    R = hi2d.shape[0]
-    W = words.shape[0]
-    tile = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0))
-    return pl.pallas_call(
-        functools.partial(_kernel_single, chain=chain),
-        grid=(R // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((W,), lambda i: (0,)),
-            tile,
-            tile,
-        ],
-        out_specs=[tile, tile],
-        out_shape=[jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32),
-                   jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32)],
-        interpret=interpret,
-    )(words, hi2d, lo2d)
+    FilterService bank dispatch for LSM chain filters): membership +
+    sequential probe count (1 + stage-1 pass — a sequential querier
+    touches the Othello stage only when stage 1 fires, the paper's Fig 7b
+    accounting). Returns (member, probes) int32 [R, 128]."""
+    _, xor_params, oth_params = chain
+    s1 = _chain_stage1(words, hi2d, lo2d, xor_params)
+    ma, mb, seed, off_a, off_b = oth_params
+    s2 = othello_hit(words, hi2d, lo2d, ma=ma, mb=mb, seed=seed,
+                     offset_a=off_a, offset_b=off_b)
+    if xor_params is None:
+        probes = jnp.ones(hi2d.shape, dtype=jnp.int32)
+    else:
+        probes = 1 + s1.astype(jnp.int32)
+    return (s1 & s2).astype(jnp.int32), probes

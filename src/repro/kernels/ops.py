@@ -1,7 +1,8 @@
 """jit'd public wrappers: filter object + raw uint64 keys in, bool out.
 
 These handle padding/tiling (common.py) and extract static layout params
-from the core filter objects, so callers never touch BlockSpecs.
+from the core filter objects, so callers never touch key lanes or
+layouts.
 """
 from __future__ import annotations
 
@@ -26,32 +27,30 @@ def _prep_keys(keys: np.ndarray):
     return jnp.asarray(hi2d), jnp.asarray(lo2d), n
 
 
-def bloom_query(f: BloomFilter, keys: np.ndarray, interpret: bool = True) -> np.ndarray:
+def bloom_query(f: BloomFilter, keys: np.ndarray) -> np.ndarray:
     hi2d, lo2d, n = _prep_keys(keys)
     words = jnp.asarray(common.pad_table(f.words))
-    out = bloom_probe(words, hi2d, lo2d, m_bits=f.m_bits, k=f.k, seed=f.seed,
-                      interpret=interpret)
+    out = bloom_probe(words, hi2d, lo2d, m_bits=f.m_bits, k=f.k, seed=f.seed)
     return np.asarray(common.unblockify(out, n)).astype(bool)
 
 
-def xor_query(f: XorFilter, keys: np.ndarray, interpret: bool = True) -> np.ndarray:
+def xor_query(f: XorFilter, keys: np.ndarray) -> np.ndarray:
     hi2d, lo2d, n = _prep_keys(keys)
     lay = f.tbl.layout
     table = jnp.asarray(common.pad_table(f.tbl.table))
     out = xor_probe(table, hi2d, lo2d, mode=lay.mode, seed=lay.seed,
                     seg_len=lay.seg_len, n_seg=lay.n_seg, alpha=f.tbl.alpha,
-                    fp_seed=f.fp_seed, interpret=interpret)
+                    fp_seed=f.fp_seed)
     return np.asarray(common.unblockify(out, n)).astype(bool)
 
 
-def exact_query(f: ExactBloomier, keys: np.ndarray, interpret: bool = True) -> np.ndarray:
+def exact_query(f: ExactBloomier, keys: np.ndarray) -> np.ndarray:
     hi2d, lo2d, n = _prep_keys(keys)
     lay = f.tbl.layout
     table = jnp.asarray(common.pad_table(f.tbl.table))
     out = exact_probe(table, hi2d, lo2d, mode=lay.mode, seed=lay.seed,
                       seg_len=lay.seg_len, n_seg=lay.n_seg,
-                      strategy=f.strategy, bit_seed=f.bit_seed,
-                      interpret=interpret)
+                      strategy=f.strategy, bit_seed=f.bit_seed)
     return np.asarray(common.unblockify(out, n)).astype(bool)
 
 
@@ -66,24 +65,22 @@ def chained_and_params(layout) -> dict:
         strategy=e.strategy, bit_seed=e.bit_seed)
 
 
-def chained_query(f: ChainedFilterAnd, keys: np.ndarray, interpret: bool = True) -> np.ndarray:
+def chained_query(f: ChainedFilterAnd, keys: np.ndarray) -> np.ndarray:
     hi2d, lo2d, n = _prep_keys(keys)
     tables, layout = f.to_tables()
     member, _ = chained_probe(jnp.asarray(tables), hi2d, lo2d,
-                              interpret=interpret,
                               **chained_and_params(layout))
     return np.asarray(common.unblockify(member, n)).astype(bool)
 
 
 def cascade_query(f: ChainedFilterCascade, keys: np.ndarray,
-                  interpret: bool = True, with_probes: bool = False):
+                  with_probes: bool = False):
     """Fused whole-cascade probe: bool member [n] (and sequential probe
     counts [n] when ``with_probes``)."""
     hi2d, lo2d, n = _prep_keys(keys)
     tables, layout = f.to_tables()
     member, probes = cascade_probe(jnp.asarray(tables), hi2d, lo2d,
-                                   layers=layout.probe_params(),
-                                   interpret=interpret)
+                                   layers=layout.probe_params())
     out = np.asarray(common.unblockify(member, n)).astype(bool)
     if with_probes:
         return out, np.asarray(common.unblockify(probes, n))

@@ -1,6 +1,7 @@
-"""Pure-jnp oracles for every filter kernel. These are the ground truth the
-Pallas kernels (interpret=True here, Mosaic on real TPUs) must match
-bit-for-bit across shape/dtype sweeps (tests/test_kernels.py)."""
+"""Pure-jnp oracles for every filter probe. These are the ground truth the
+fused probe programs in this package must match bit-for-bit across
+shape/dtype sweeps (tests/test_kernels.py). They are written per filter,
+on unpacked tables and without offsets, independently of the fused code."""
 from __future__ import annotations
 
 import jax.numpy as jnp

@@ -1,11 +1,10 @@
-"""Pallas TPU kernel: Bloomier/XOR-filter probe (3 gathers + XOR + compare).
+"""Bloomier/XOR-filter probe (3 gathers + XOR + compare): jitted XLA.
 
 Covers both the approximate (α-bit fingerprint) and exact (1-bit, strategy
 a/b) Bloomier variants — the exact case is the α=1 path with the fingerprint
-replaced by the strategy bit. Table VMEM-resident, keys in (8,128) tiles.
-The slot/lookup math lives in common.py (shared with the fused chained and
-cascade kernels) and takes a static ``offset`` so the table may be a slice
-of a packed FilterBank buffer.
+replaced by the strategy bit. The slot/lookup math lives in common.py
+(shared with the fused chained and cascade probes) and takes a static
+``offset`` so the table may be a slice of a packed FilterBank buffer.
 """
 from __future__ import annotations
 
@@ -13,71 +12,31 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core import hashing as H
-from .common import BLOCK_ROWS, BLOCK_COLS, xor_lookup
+from .common import xor_lookup
 
 
-def _kernel(table_ref, hi_ref, lo_ref, out_ref, *, mode, seed, seg_len, n_seg,
-            alpha, fp_seed, offset):
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    v = xor_lookup(table_ref[...], hi, lo, mode=mode, seed=seed,
-                   seg_len=seg_len, n_seg=n_seg, alpha=alpha, offset=offset)
-    fp = H.jx_hash_u32(hi, lo, fp_seed) & jnp.uint32((1 << alpha) - 1)
-    out_ref[...] = (v == fp).astype(jnp.int32)
+@functools.partial(jax.jit, static_argnames=("mode", "seed", "seg_len", "n_seg",
+                                             "alpha", "fp_seed", "offset"))
+def xor_probe(table, hi2d, lo2d, *, mode: str, seed: int, seg_len: int,
+              n_seg: int, alpha: int, fp_seed: int, offset: int = 0):
+    """-> int32 [R, 128] (1 = fingerprint match)."""
+    v = xor_lookup(table, hi2d, lo2d, mode=mode, seed=seed, seg_len=seg_len,
+                   n_seg=n_seg, alpha=alpha, offset=offset)
+    fp = H.jx_hash_u32(hi2d, lo2d, fp_seed) & jnp.uint32((1 << alpha) - 1)
+    return (v == fp).astype(jnp.int32)
 
 
-def _kernel_exact(table_ref, hi_ref, lo_ref, out_ref, *, mode, seed, seg_len,
-                  n_seg, strategy, bit_seed, offset):
-    hi = hi_ref[...]
-    lo = lo_ref[...]
-    v = xor_lookup(table_ref[...], hi, lo, mode=mode, seed=seed,
-                   seg_len=seg_len, n_seg=n_seg, alpha=1, offset=offset)
+@functools.partial(jax.jit, static_argnames=("mode", "seed", "seg_len", "n_seg",
+                                             "strategy", "bit_seed", "offset"))
+def exact_probe(table, hi2d, lo2d, *, mode: str, seed: int, seg_len: int,
+                n_seg: int, strategy: str, bit_seed: int, offset: int = 0):
+    """-> int32 [R, 128] (1 = member of the exact Bloomier's positive set)."""
+    v = xor_lookup(table, hi2d, lo2d, mode=mode, seed=seed, seg_len=seg_len,
+                   n_seg=n_seg, alpha=1, offset=offset)
     if strategy == "a":
-        tgt = H.jx_hash_u32(hi, lo, bit_seed) & jnp.uint32(1)
+        tgt = H.jx_hash_u32(hi2d, lo2d, bit_seed) & jnp.uint32(1)
     else:
         tgt = jnp.uint32(1)
-    out_ref[...] = (v == tgt).astype(jnp.int32)
-
-
-def _call(kernel, table, hi2d, lo2d, interpret):
-    R = hi2d.shape[0]
-    W = table.shape[0]
-    return pl.pallas_call(
-        kernel,
-        grid=(R // BLOCK_ROWS,),
-        in_specs=[
-            pl.BlockSpec((W,), lambda i: (0,)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, BLOCK_COLS), jnp.int32),
-        interpret=interpret,
-    )(table, hi2d, lo2d)
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "seed", "seg_len", "n_seg",
-                                             "alpha", "fp_seed", "offset",
-                                             "interpret"))
-def xor_probe(table, hi2d, lo2d, *, mode: str, seed: int, seg_len: int,
-              n_seg: int, alpha: int, fp_seed: int, offset: int = 0,
-              interpret: bool = True):
-    k = functools.partial(_kernel, mode=mode, seed=seed, seg_len=seg_len,
-                          n_seg=n_seg, alpha=alpha, fp_seed=fp_seed,
-                          offset=offset)
-    return _call(k, table, hi2d, lo2d, interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("mode", "seed", "seg_len", "n_seg",
-                                             "strategy", "bit_seed", "offset",
-                                             "interpret"))
-def exact_probe(table, hi2d, lo2d, *, mode: str, seed: int, seg_len: int,
-                n_seg: int, strategy: str, bit_seed: int, offset: int = 0,
-                interpret: bool = True):
-    k = functools.partial(_kernel_exact, mode=mode, seed=seed, seg_len=seg_len,
-                          n_seg=n_seg, strategy=strategy, bit_seed=bit_seed,
-                          offset=offset)
-    return _call(k, table, hi2d, lo2d, interpret)
+    return (v == tgt).astype(jnp.int32)

@@ -55,7 +55,7 @@ class TagIndex:
     current nor pinned are pruned at each enrollment."""
 
     def __init__(self, name: str, tag_fn, *, tag_bits: int = 4,
-                 seed: int = 0, mesh=None, interpret: bool = True):
+                 seed: int = 0, mesh=None):
         if not (1 <= tag_bits <= 16):
             raise ValueError("tag_bits must be in [1, 16]")
         self.name = name
@@ -63,7 +63,6 @@ class TagIndex:
         self.tag_bits = int(tag_bits)
         self.seed = int(seed)
         self.mesh = mesh
-        self.interpret = interpret
         self.service: FilterService | None = None
         self.enrollments = 0
         self._states: dict[int, BankState | None] = {}
@@ -100,8 +99,7 @@ class TagIndex:
                 for j in range(self.tag_bits)
             ]
             if self.service is None:
-                self.service = FilterService(planes, mesh=self.mesh,
-                                             interpret=self.interpret)
+                self.service = FilterService(planes, mesh=self.mesh)
                 if self._registry is not None:
                     self._registry.register(self._qualname, self.service)
             else:
@@ -158,8 +156,7 @@ class Collection:
             raise ValueError(f"index {name!r} already exists on "
                              f"collection {self.name!r}")
         idx = TagIndex(name, tag_fn, tag_bits=tag_bits,
-                       seed=seed, mesh=self.store.mesh,
-                       interpret=self.store.interpret)
+                       seed=seed, mesh=self.store.mesh)
         if self._registry is not None:
             idx._registry = self._registry
             idx._qualname = f"{self.name}/{name}"

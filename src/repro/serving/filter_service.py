@@ -7,13 +7,13 @@ Paper mapping
   Here the same idea is lifted one level: ``FilterBank.pack`` flattens N
   heterogeneous filters (Bloom, Xor, ExactBloomier, ChainedFilterAnd,
   ChainedFilterCascade) into ONE 128-word-aligned uint32 buffer plus static
-  layout descriptors (core.tables), so every fused kernel gathers from a
-  single VMEM-resident table and each (8, 128) key tile is loaded exactly
-  once per filter stack — never per layer.
+  layout descriptors (core.tables), so every fused probe gathers from a
+  single device-resident table and each key batch is loaded exactly once
+  per filter stack — never per layer.
 - **§5.3 (cascade probing).** ``ChainedFilterCascade`` queries are served by
-  the fused ``cascade_probe`` kernel: all Bloom layers and the
-  first-zero-layer parity rule evaluate in one kernel launch instead of one
-  device dispatch per layer. The kernel also reports the sequential probe
+  the fused ``cascade_probe``: all Bloom layers and the first-zero-layer
+  parity rule evaluate in one device program instead of one device
+  dispatch per layer. It also reports the sequential probe
   count min(first_zero, L) — the number of layer touches a short-circuiting
   querier pays — which the service aggregates into its stats, mirroring the
   paper's memory-access accounting (Tab. 3 / Fig. 10).
@@ -24,8 +24,8 @@ Paper mapping
 
 Scale-out: key blocks are sharded across devices with ``shard_map`` over a
 1-D ``data`` mesh (CPU multi-device via ``--xla_force_host_platform_
-device_count`` in tests); the packed table buffer is replicated — filters
-are small by construction (§4) — and each device probes its own key rows.
+device_count`` in tests); the packed table buffer is replicated and each
+device probes its own key rows.
 """
 from __future__ import annotations
 
@@ -36,7 +36,6 @@ from dataclasses import dataclass, field
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.bloom import BloomFilter
@@ -102,23 +101,22 @@ class FilterBank:
 # fused per-layout dispatch (single jit, layouts static)
 # ---------------------------------------------------------------------------
 
-def _probe_one(tables, hi2d, lo2d, lay, interpret: bool):
+def _probe_one(tables, hi2d, lo2d, lay):
     """-> (member, probes) int32 [R, 128] for one filter layout."""
     if isinstance(lay, BloomTable):
         m = bloom_probe(tables, hi2d, lo2d, m_bits=lay.m_bits, k=lay.k,
-                        seed=lay.seed, offset=lay.offset, interpret=interpret)
+                        seed=lay.seed, offset=lay.offset)
         return m, jnp.ones_like(m)
     if isinstance(lay, XorTable):
         m = xor_probe(tables, hi2d, lo2d, mode=lay.mode, seed=lay.seed,
                       seg_len=lay.seg_len, n_seg=lay.n_seg, alpha=lay.alpha,
-                      fp_seed=lay.fp_seed, offset=lay.offset,
-                      interpret=interpret)
+                      fp_seed=lay.fp_seed, offset=lay.offset)
         return m, jnp.ones_like(m)
     if isinstance(lay, ExactTable):
         m = exact_probe(tables, hi2d, lo2d, mode=lay.mode, seed=lay.seed,
                         seg_len=lay.seg_len, n_seg=lay.n_seg,
                         strategy=lay.strategy, bit_seed=lay.bit_seed,
-                        offset=lay.offset, interpret=interpret)
+                        offset=lay.offset)
         return m, jnp.ones_like(m)
     if isinstance(lay, OthelloTable):
         m = othello_hit(tables, hi2d, lo2d, ma=lay.ma, mb=lay.mb,
@@ -126,24 +124,21 @@ def _probe_one(tables, hi2d, lo2d, lay, interpret: bool):
                         offset_b=lay.offset_b).astype(jnp.int32)
         return m, jnp.ones_like(m)
     if isinstance(lay, LsmChainLayout):
-        return lsm_chain_probe(tables, hi2d, lo2d,
-                               chain=lay.probe_params(), interpret=interpret)
+        return lsm_chain_probe(tables, hi2d, lo2d, chain=lay.probe_params())
     if isinstance(lay, ChainedAndLayout):
-        return chained_probe(tables, hi2d, lo2d, interpret=interpret,
-                             **chained_and_params(lay))
+        return chained_probe(tables, hi2d, lo2d, **chained_and_params(lay))
     if isinstance(lay, CascadeLayout):
-        return cascade_probe(tables, hi2d, lo2d, layers=lay.probe_params(),
-                             interpret=interpret)
+        return cascade_probe(tables, hi2d, lo2d, layers=lay.probe_params())
     raise TypeError(f"unknown filter layout {type(lay).__name__}")
 
 
-@functools.partial(jax.jit, static_argnames=("layouts", "interpret"))
-def bank_probe(tables, hi2d, lo2d, *, layouts: tuple, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("layouts",))
+def bank_probe(tables, hi2d, lo2d, *, layouts: tuple):
     """Probe every filter in the bank on one key block.
     -> (member, probes) int32 [F, R, 128]."""
     members, probes = [], []
     for lay in layouts:
-        m, p = _probe_one(tables, hi2d, lo2d, lay, interpret)
+        m, p = _probe_one(tables, hi2d, lo2d, lay)
         members.append(m)
         probes.append(p)
     return jnp.stack(members), jnp.stack(probes)
@@ -203,8 +198,7 @@ class FilterService:
     reference swap). A probe stream that captured the old state — e.g. a
     pinned storage generation — finishes against it unchanged."""
 
-    def __init__(self, filters: list, *, mesh=None, interpret: bool = True):
-        self.interpret = interpret
+    def __init__(self, filters: list, *, mesh=None):
         if mesh is None:
             mesh = jax.make_mesh((jax.device_count(),), ("data",))
         self.mesh = mesh
@@ -244,14 +238,13 @@ class FilterService:
         bank = FilterBank.pack(filters)
         bank.tables.setflags(write=False)      # immutable once staged
         tables = jnp.asarray(bank.tables)
-        layouts, interp = bank.layouts, self.interpret
-        probe_fn = jax.jit(shard_map(
-            lambda t, h, l: bank_probe(t, h, l, layouts=layouts,
-                                       interpret=interp),
+        layouts = bank.layouts
+        probe_fn = jax.jit(jax.shard_map(
+            lambda t, h, l: bank_probe(t, h, l, layouts=layouts),
             mesh=self.mesh,
             in_specs=(P(), P("data", None), P("data", None)),
             out_specs=(P(None, "data", None), P(None, "data", None)),
-            check_rep=False,
+            check_vma=False,
         ))
         if warm:
             # jit-warm: trace + compile now, so the first probe after
@@ -323,8 +316,7 @@ class FilterService:
         state = self._state
         hi2d, lo2d, n = self._block_keys(keys)
         member, _ = bank_probe(state.tables, hi2d, lo2d,
-                               layouts=(state.bank.layouts[index],),
-                               interpret=self.interpret)
+                               layouts=(state.bank.layouts[index],))
         return np.asarray(member).reshape(-1)[:n].astype(bool)
 
     def refresh_tables(self, filters: list) -> None:

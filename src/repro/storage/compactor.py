@@ -15,9 +15,10 @@ A ``poll_s`` heartbeat backstops missed kicks. Every step's work funnels
 through the store's ordinary ``_publish`` swap point, so readers observe
 background compaction exactly as they observe foreground compaction: as a
 sequence of immutable generations. Step failures (publish-hook errors
-included) are recorded on ``errors`` and never kill the loop — a broken
-secondary-index hook must not stop compaction and wedge every writer at
-the cap.
+included) are logged with their traceback, recorded on ``errors`` and never
+kill the loop — a broken secondary-index hook must not stop compaction and
+wedge every writer at the cap. Callers that must not carry on after a
+failure check ``errors`` (``LsmStore.background_errors``).
 
 Thread-safety contract: the loop takes the store's mutator lock ``_wl``
 for each step and the small lock ``_mu`` only transiently inside it
@@ -27,8 +28,11 @@ be unblocked by the compactor it is waiting for.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
+
+_log = logging.getLogger(__name__)
 
 
 class BackgroundCompactor:
@@ -103,6 +107,7 @@ class BackgroundCompactor:
                     if progressed:
                         self.steps += 1
             except Exception as exc:        # isolate: the loop must survive
+                _log.exception("background compaction step failed")
                 self.errors.append(exc)
             finally:
                 self._idle.set()

@@ -119,10 +119,10 @@ class Generation:
         live = ~cat_t[first_idx]
         return uk[live], cat_v[first_idx][live]
 
-    def probe_batch(self, keys: np.ndarray, *, interpret: bool = True
+    def probe_batch(self, keys: np.ndarray
                     ) -> tuple[np.ndarray, np.ndarray]:
         """Fused probe of every SSTable filter of THIS generation for the
-        whole key batch in ONE kernel launch -> (first_hit int32 [n] ∈
+        whole key batch in ONE device program -> (first_hit int32 [n] ∈
         [0, N], hits_mask int32 [n]); first_hit == N means no filter
         fired. Reads only the generation's own frozen buffers — probing
         an old generation after newer ones publish is bit-identical."""
@@ -133,7 +133,7 @@ class Generation:
         hi2d, lo2d, n = common.blockify(hi, lo)
         first, mask = lsm_probe(self.tables_dev, jnp.asarray(hi2d),
                                 jnp.asarray(lo2d), self.params_dev,
-                                chains=self.chains, interpret=interpret)
+                                chains=self.chains)
         first, mask = jax.device_get((first, mask))   # one host pull for both
         return first.reshape(-1)[:n], mask.reshape(-1)[:n]
 

@@ -193,7 +193,7 @@ class StoreStats:
 @dataclass
 class LsmStore:
     """Point-query LSM store: memtable + newest-first immutable SSTables,
-    batched filter-guarded reads through one fused kernel launch against
+    batched filter-guarded reads through one fused device probe against
     generation-tagged immutable banks."""
 
     filter_kind: str = "chained"
@@ -206,7 +206,6 @@ class LsmStore:
     auto_compact: bool = True
     table_cap: int = MAX_TABLES       # admission control: stall/fail at this
     stall_timeout_s: float = 5.0      # bounded admission wait before WriteStall
-    interpret: bool = True
     mesh: object = None
 
     sstables: list = field(default_factory=list, repr=False)   # newest first
@@ -714,8 +713,7 @@ class LsmStore:
             if len(live) != len(tables_bs):
                 raise RuntimeError("mixed filtered/filterless tables unsupported")
             if self.service is None:
-                self.service = FilterService(live, mesh=self.mesh,
-                                             interpret=self.interpret)
+                self.service = FilterService(live, mesh=self.mesh)
             elif len(live) != self.service.bank.n_filters:
                 # filter added/removed: layouts certainly changed — skip the
                 # refresh_tables attempt (it would pack the whole bank once
@@ -892,9 +890,9 @@ class LsmStore:
     # -------------------------------------------------------------- read path
     def probe_batch(self, keys: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """Fused probe of every SSTable filter of the CURRENT generation for
-        the whole batch in ONE kernel launch -> (first_hit int32 [n] ∈
+        the whole batch in ONE device probe -> (first_hit int32 [n] ∈
         [0, N], hits_mask int32 [n]); first_hit == N means no filter fired."""
-        return self._gen.probe_batch(keys, interpret=self.interpret)
+        return self._gen.probe_batch(keys)
 
     def _resolve_chained(self, stats, sstables, keys, first, found, vals,
                          reads, idx):
@@ -963,7 +961,7 @@ class LsmStore:
         idx = np.flatnonzero(rest)
         sub = keys[idx]
         stats.probed += len(sub)
-        first, mask = gen.probe_batch(sub, interpret=self.interpret)
+        first, mask = gen.probe_batch(sub)
         if self.filter_kind == "chained":
             self._resolve_chained(stats, gen.sstables, sub, first, found,
                                   vals, reads, idx)

@@ -25,7 +25,7 @@ from repro.serving.filter_service import FilterBank, FilterService
 from repro.serving.prefix_cache import TieredPrefixCache, TierSpec
 
 KEYS = H.random_keys(60_000, seed=23)
-QUERIES = KEYS[:8192]   # kept modest: interpret-mode kernels compile per layout
+QUERIES = KEYS[:8192]   # kept modest: each layout compiles its own probe
 
 
 def _build(kind: str, seed: int = 0):

@@ -1,7 +1,7 @@
 """Generation/snapshot lifecycle (ISSUE 5): publish immutability, snapshot
 pinning across compaction, deferred tombstone GC until release, refcount
-hygiene (no generation leaks), old-generation kernel-boundary probe parity
-(interpret=True), mid-rebuild read atomicity, and the single-swap-point
+hygiene (no generation leaks), old-generation probe-boundary parity,
+mid-rebuild read atomicity, and the single-swap-point
 contract for the legacy ``compact()`` path (scan cursors started before a
 compaction see the pre-compaction key set).
 """
@@ -267,17 +267,17 @@ def test_gc_not_deferred_for_tombstones_no_snapshot_sees():
     snap.close()
 
 
-# ------------------------------------------ kernel boundary (interpret=True)
+# ------------------------------------------------------------ probe boundary
 def test_old_generation_probe_bit_identical_after_rebuild():
     """Probing an old generation's packed bank AFTER a rebuild publishes a
     new one returns bit-identical results to pre-swap probes — straight
-    through the fused kernel (interpret=True) with the old generation's
+    through the fused probe with the old generation's
     own frozen tables/params."""
     store = _store(seed=8)
     _fill(store, 3, per=220)
     gen_a = store.generation
     q = np.concatenate([KEYS[:3 * 220], KEYS[5000:6200]])
-    first_pre, mask_pre = gen_a.probe_batch(q, interpret=True)
+    first_pre, mask_pre = gen_a.probe_batch(q)
     # rebuild: new table count -> structural publish of a NEW generation
     ks = np.sort(KEYS[1000:1400])
     store.put_batch(ks, ks)
@@ -285,15 +285,14 @@ def test_old_generation_probe_bit_identical_after_rebuild():
     gen_b = store.generation
     assert gen_b.gen_id > gen_a.gen_id
     assert gen_b.chains != gen_a.chains
-    first_post, mask_post = gen_a.probe_batch(q, interpret=True)
+    first_post, mask_post = gen_a.probe_batch(q)
     np.testing.assert_array_equal(first_post, first_pre)
     np.testing.assert_array_equal(mask_post, mask_pre)
     # and via a raw lsm_probe launch on the generation's own buffers
     hi, lo = H.np_split_u64(q)
     hi2d, lo2d, n = common.blockify(hi, lo)
     first_raw, mask_raw = lsm_probe(gen_a.tables_dev, hi2d, lo2d,
-                                    gen_a.params_dev, chains=gen_a.chains,
-                                    interpret=True)
+                                    gen_a.params_dev, chains=gen_a.chains)
     np.testing.assert_array_equal(
         np.asarray(common.unblockify(first_raw, n)), first_pre)
     np.testing.assert_array_equal(
@@ -305,7 +304,7 @@ def test_old_generation_probe_bit_identical_after_rebuild():
     with pytest.raises(ValueError):
         lsm_probe(gen_a.tables_dev, hi2d, lo2d,
                   np.zeros(2 * len(gen_a.params), np.uint32),
-                  chains=gen_a.chains, interpret=True)
+                  chains=gen_a.chains)
 
 
 def test_get_batch_mid_rebuild_sees_one_consistent_generation():

@@ -1,6 +1,5 @@
-"""Pallas kernel sweeps: every kernel must match its pure-jnp ref.py oracle
-bit-for-bit across shapes, layouts and fingerprint widths (interpret=True
-executes the kernel body on CPU; BlockSpecs are the real TPU tiling)."""
+"""Probe sweeps: every fused probe must match its pure-jnp ref.py oracle
+bit-for-bit across shapes, layouts and fingerprint widths."""
 import numpy as np
 import jax.numpy as jnp
 import pytest

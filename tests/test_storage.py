@@ -322,7 +322,7 @@ def _filled_store(seed=31, kind="chained", **kw):
 
 
 def test_lsm_probe_ignores_tombstone_only_tables():
-    """Kernel boundary (interpret=True): a table whose ONLY physical match
+    """Probe boundary: a table whose ONLY physical match
     for a key is a tombstone must contribute neither its hits_mask bit nor
     the first-hit index — the deleted key's exclusion happens at filter
     build/update time and the fused kernel must observe it."""
@@ -336,7 +336,7 @@ def test_lsm_probe_ignores_tombstone_only_tables():
     hi, lo = H.np_split_u64(dels)
     hi2d, lo2d, n = common.blockify(hi, lo)
     first, mask = lsm_probe(store._tables_dev, hi2d, lo2d,
-                            chains=store._chains, interpret=True)
+                            chains=store._chains)
     first = np.asarray(common.unblockify(first, n))
     mask = np.asarray(common.unblockify(mask, n))
     assert (mask == 0).all()            # no table's filter fires at all
