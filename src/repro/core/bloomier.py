@@ -184,6 +184,7 @@ class BloomierTable:
     table: np.ndarray = field(repr=False)   # uint32 [m], low alpha bits used
     n_keys: int = 0
     build_rounds: int = 0
+    build_attempts: int = 1      # layouts ``build`` tried
 
     @classmethod
     def build(cls, keys: np.ndarray, values: np.ndarray, alpha: int,
@@ -209,7 +210,8 @@ class BloomierTable:
                 continue
             table = bulk_assign(rounds, h0, h1, h2, values, layout.m)
             return cls(layout=layout, alpha=alpha, table=table,
-                       n_keys=len(keys), build_rounds=len(rounds))
+                       n_keys=len(keys), build_rounds=len(rounds),
+                       build_attempts=attempt + 1)
         raise PeelingFailed(f"construction failed after {max_retries} retries: {last}")
 
     # -- lookup (returns the α-bit decoded value; arbitrary for non-keys) ----

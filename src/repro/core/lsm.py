@@ -23,6 +23,7 @@ import numpy as np
 from .bloom import BloomFilter
 from .othello import DynamicExactFilter
 from .bloomier import XorFilter
+from repro.trace import span
 
 
 @dataclass
@@ -172,8 +173,9 @@ class ChainedTableFilter:
         keys = np.asarray(keys, dtype=np.uint64)
         other = np.asarray(other_keys, dtype=np.uint64)
         f1 = XorFilter.build(keys, fp_alpha, seed=seed1)
-        other = other[~_in_sorted(np.sort(keys), other)]
-        fp = other[f1.query(other)] if len(other) else other
+        with span("core.chained.stage1", n=len(other)):
+            other = other[~_in_sorted(np.sort(keys), other)]
+            fp = other[f1.query(other)] if len(other) else other
         f2 = DynamicExactFilter.build(keys, fp, seed=seed2)
         return cls(f1=f1, f2=f2)
 

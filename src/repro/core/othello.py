@@ -84,6 +84,8 @@ class Othello:
     _pot: np.ndarray = field(default=None, init=False, repr=False)
     _rootbit: np.ndarray = field(default=None, init=False, repr=False)
     _dirty: bool = field(default=False, init=False, repr=False)
+    build_attempts: int = field(default=1, init=False, repr=False,
+                                compare=False)   # layouts ``build`` tried
 
     def __post_init__(self):
         if self.bits_a is None:
@@ -137,6 +139,7 @@ class Othello:
                 continue
             oth = cls(ma=m, mb=m, seed=s)
             oth._adopt_peeled(uk, uv, u, v, rounds)
+            oth.build_attempts = attempt + 1
             return oth
         raise RuntimeError(f"othello build failed: {last}")
 

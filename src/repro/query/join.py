@@ -47,8 +47,9 @@ def bank_member(view: CollectionView, keys: np.ndarray) -> np.ndarray:
         gen = view.snap.gen
         if gen.n_tables:
             store = view.collection.store
-            first, mask = gen.probe_batch(keys[rest])
-            store.snap_stats.probed += int(rest.sum())
+            acc = {"probed": int(rest.sum())}
+            first, mask = gen.probe_batch(keys[rest], acc)
+            store.snap_stats.add(acc)
             maybe[rest] = mask != 0
         # else: empty generation — nothing generation-resident exists
     return maybe

@@ -30,7 +30,7 @@ lets go.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 import jax
@@ -41,6 +41,7 @@ from repro.core.lsm import SSTable
 from repro.core.tables import TABLE_ALIGN
 from repro.kernels import common
 from repro.kernels.lsm_probe import lsm_probe, pack_chain_params
+from repro.trace import clock, span
 
 
 @dataclass(frozen=True)
@@ -64,6 +65,10 @@ class Generation:
     params_dev: object           # jnp.ndarray mirror of ``params``
     bank_state: object           # serving BankState | None
     filter_bits: int             # total filter bits at publish time
+    # set by the generation's first probe, which carries any compile of its
+    # layout; probes that race it may count as first too
+    _probed: bool = field(default=False, init=False, repr=False,
+                          compare=False)
 
     @classmethod
     def create(cls, gen_id: int, sstables, chains, tables: np.ndarray,
@@ -119,23 +124,53 @@ class Generation:
         live = ~cat_t[first_idx]
         return uk[live], cat_v[first_idx][live]
 
-    def probe_batch(self, keys: np.ndarray
+    def probe_batch(self, keys: np.ndarray, acc: dict | None = None
                     ) -> tuple[np.ndarray, np.ndarray]:
         """Fused probe of every SSTable filter of THIS generation for the
         whole key batch in ONE device program -> (first_hit int32 [n] ∈
         [0, N], hits_mask int32 [n]); first_hit == N means no filter
         fired. Reads only the generation's own frozen buffers — probing
-        an old generation after newer ones publish is bit-identical."""
+        an old generation after newer ones publish is bit-identical.
+        Given a call's counts ``acc``, sets in it the launch, its key
+        slots and each phase's time."""
         keys = np.asarray(keys, dtype=np.uint64)
         if not self.sstables:
             raise RuntimeError("no SSTables; flush first")
-        hi, lo = H.np_split_u64(keys)
-        hi2d, lo2d, n = common.blockify(hi, lo)
-        first, mask = lsm_probe(self.tables_dev, jnp.asarray(hi2d),
-                                jnp.asarray(lo2d), self.params_dev,
-                                chains=self.chains)
-        first, mask = jax.device_get((first, mask))   # one host pull for both
-        return first.reshape(-1)[:n], mask.reshape(-1)[:n]
+        if self._probed:
+            return self._probe(keys, acc)
+        object.__setattr__(self, "_probed", True)
+        t0 = clock()
+        with span("gen.probe.first", gen=self.gen_id):
+            out = self._probe(keys, acc)
+        if acc is not None:
+            acc.update(first_probes=1, first_probe_ns=clock() - t0)
+        return out
+
+    def _probe(self, keys: np.ndarray, acc: dict | None):
+        t0 = clock()
+        with span("gen.probe.split"):
+            hi, lo = H.np_split_u64(keys)
+            hi2d, lo2d, n = common.blockify(hi, lo)
+        t1 = clock()
+        with span("gen.probe.h2d"):
+            hi_dev, lo_dev = jnp.asarray(hi2d), jnp.asarray(lo2d)
+        t2 = clock()
+        with span("gen.probe.launch"):
+            first, mask = lsm_probe(self.tables_dev, hi_dev, lo_dev,
+                                    self.params_dev, chains=self.chains)
+        t3 = clock()
+        with span("gen.probe.d2h"):
+            first, mask = jax.device_get((first, mask))   # one pull for both
+            first, mask = first.reshape(-1)[:n], mask.reshape(-1)[:n]
+        t4 = clock()
+        with span("gen.probe.free"):
+            del hi_dev, lo_dev          # the key tiles' device buffers
+        if acc is not None:
+            acc.update(probe_launches=1, probe_slots=hi2d.size,
+                       probe_split_ns=t1 - t0, probe_h2d_ns=t2 - t1,
+                       probe_launch_ns=t3 - t2, probe_d2h_ns=t4 - t3,
+                       probe_free_ns=clock() - t4)
+        return first, mask
 
 
 class Snapshot:

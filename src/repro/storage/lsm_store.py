@@ -63,6 +63,7 @@ which is precisely the tail the chain rule removes.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 import types
@@ -76,8 +77,12 @@ from repro.core.tables import TABLE_ALIGN, BloomTable, LsmChainLayout
 from repro.kernels.lsm_probe import MAX_TABLES
 from repro.serving.filter_service import FilterService
 from repro.storage.generation import Generation, Snapshot
+from repro.trace import clock, gc_counters, install_gc_hook, span, traced
 
 FILTER_KINDS = ("chained", "bloom", "none")
+# thread CPU time is a system call (~6 µs on a v5e host, against 0.08 µs for
+# the wall clock), so reads take it on one call in this many
+CPU_SAMPLE = 16
 
 
 class WriteStall(RuntimeError):
@@ -149,6 +154,14 @@ class _ScanCursor:
         self.close()
 
 
+def _layout_attempts(f) -> int:
+    """Layouts a filter's build tried: each peeled stage retries on a
+    layout that fails to peel; a Bloom filter has one."""
+    if isinstance(f, ChainedTableFilter):
+        return f.f1.tbl.build_attempts + f.f2.oth.build_attempts
+    return 1
+
+
 def _chain_descriptor(layout) -> tuple:
     """Static per-table descriptor for ``lsm_probe`` from a bank layout."""
     if isinstance(layout, LsmChainLayout):
@@ -160,6 +173,14 @@ def _chain_descriptor(layout) -> tuple:
 
 @dataclass
 class StoreStats:
+    """Cumulative counters of one store. Times (``*_ns``) are wall time on
+    ``perf_counter_ns``, summed over calls, each read at the boundaries of
+    the span named beside it (``repro.trace.span``). A read's phases leave
+    out only the code between them (the calls from one phase to the next,
+    and any wait for the interpreter lock that lands there): ``get_ns``
+    less their sum. The read and write paths add one call's counts at
+    once, through ``add``."""
+
     puts: int = 0
     deletes: int = 0
     gets: int = 0
@@ -183,10 +204,49 @@ class StoreStats:
     bg_compactions: int = 0          # merge runs executed by _background_step
     bg_gc_sweeps: int = 0            # deferred-GC sweeps run off the close path
     publish_hook_errors: int = 0     # hook failures isolated by _run_publish_hooks
+    # read path
+    get_calls: int = 0               # lsm.get_batch
+    get_ns: int = 0
+    get_cpu_calls: int = 0           # calls whose thread CPU time was taken
+    get_cpu_ns: int = 0              # ... and that time (one in CPU_SAMPLE)
+    get_mu_wait_ns: int = 0          # lsm.get.mu_wait: acquiring _mu
+    overlay_ns: int = 0              # lsm.overlay: memtable, flushing run
+    probe_split_ns: int = 0          # gen.probe.split: key halves, tiles
+    probe_h2d_ns: int = 0            # gen.probe.h2d: key tiles to the device
+    probe_launch_ns: int = 0         # gen.probe.launch: lsm_probe dispatch
+    probe_d2h_ns: int = 0            # gen.probe.d2h: wait + result pull
+    probe_free_ns: int = 0           # gen.probe.free: the key tiles' release
+    probe_launches: int = 0
+    probe_slots: int = 0             # key slots launched, padding included
+    first_probes: int = 0            # gen.probe.first: a generation's first
+    first_probe_ns: int = 0
+    resolve_ns: int = 0              # lsm.resolve: the SSTable reads
+    # write path
+    put_calls: int = 0               # lsm.put_batch
+    put_ns: int = 0
+    put_mu_wait_ns: int = 0          # lsm.put.mu_wait: acquiring _mu
+    merge_ns: int = 0                # lsm.memtable.merge, under _mu
+    memtable_rows_spliced: int = 0   # memtable rows copied by np.insert
+    # builds
+    filter_build_ns: int = 0         # lsm.filter_build
+    filter_build_attempts: int = 0   # layouts tried, every stage of a filter
+    publish_ns: int = 0              # lsm.publish: bank pack, upload, swap
+    _lock: threading.Lock = field(default_factory=threading.Lock, init=False,
+                                  repr=False, compare=False)
+
+    def add(self, counts: dict) -> None:
+        """Add one call's counts in one update: concurrent callers lose no
+        increment."""
+        fields = self.__dict__
+        with self._lock:
+            for name, n in counts.items():
+                fields[name] += n
 
     def as_dict(self) -> dict:
-        d = dict(self.__dict__)
-        d["avg_reads_per_get"] = self.sstable_reads / max(1, self.gets)
+        """Every counter, and the process's garbage-collector counters."""
+        with self._lock:
+            d = {k: v for k, v in self.__dict__.items() if k != "_lock"}
+        d.update(gc_counters())
         return d
 
 
@@ -224,6 +284,8 @@ class LsmStore:
         if not (2 <= self.table_cap <= MAX_TABLES):
             raise ValueError(f"table_cap must be in [2, {MAX_TABLES}] "
                              "(the fused probe kernel's table limit)")
+        install_gc_hook()
+        self._reads = itertools.count()           # picks the CPU-timed reads
         self._flush_count = 0
         self._compact_count = 0
         # two-lock protocol (lock order: _wl then _mu, never the reverse):
@@ -297,44 +359,55 @@ class LsmStore:
 
     # ------------------------------------------------------------- write path
     def _memtable_merge(self, keys: np.ndarray, values: np.ndarray,
-                        tombs: bool) -> None:
+                        tombs: bool, acc: dict) -> None:
         """Newest-wins merge of one (deduped-last) record batch into the
         sorted array memtable; ``tombs`` marks the whole batch as tombstones
-        (deletes) or live (puts)."""
+        (deletes) or live (puts). Lock wait, merge time and spliced rows
+        are set in the call's counts ``acc``."""
         # dedupe within the batch (reversed + unique keeps the LAST write)
         uk, first_idx = np.unique(keys[::-1], return_index=True)
         uv = values[::-1][first_idx]
         ut = np.full(len(uk), tombs, dtype=bool)
-        with self._mu:
-            m = len(self._mt_keys)
-            if m < 16384 or len(uk) * 8 >= m:
-                # small memtable / large relative batch: one combined unique
-                # (newest occurrence first ⇒ batch shadows old)
-                cat_k = np.concatenate([uk, self._mt_keys])
-                cat_v = np.concatenate([uv, self._mt_vals])
-                cat_t = np.concatenate([ut, self._mt_tombs])
-                mk, fi = np.unique(cat_k, return_index=True)
-                self._mt_keys, self._mt_vals = mk, cat_v[fi]
-                self._mt_tombs = cat_t[fi]
-            else:
-                # big memtable, small batch: overwrite hits in place and
-                # splice misses by position — O(batch log + memtable), no
-                # full re-sort. Open snapshots hold COPIES of these arrays
-                # and concurrent readers resolve the overlay entirely under
-                # _mu, so the in-place writes never leak into any view.
-                pos = np.searchsorted(self._mt_keys, uk)
-                pos_c = np.minimum(pos, m - 1)
-                hit = self._mt_keys[pos_c] == uk
-                self._mt_vals[pos_c[hit]] = uv[hit]
-                self._mt_tombs[pos_c[hit]] = tombs
-                if (~hit).any():
-                    self._mt_keys = np.insert(self._mt_keys, pos[~hit],
-                                              uk[~hit])
-                    self._mt_vals = np.insert(self._mt_vals, pos[~hit],
-                                              uv[~hit])
-                    self._mt_tombs = np.insert(self._mt_tombs, pos[~hit],
-                                               tombs)
-            over = len(self._mt_keys) >= self.memtable_capacity
+        t0 = clock()
+        with span("lsm.put.mu_wait"):
+            self._mu.acquire()
+        t1 = clock()
+        try:
+            with span("lsm.memtable.merge", n=len(uk)):
+                m = len(self._mt_keys)
+                if m < 16384 or len(uk) * 8 >= m:
+                    # small memtable / large relative batch: one combined
+                    # unique (newest occurrence first ⇒ batch shadows old)
+                    cat_k = np.concatenate([uk, self._mt_keys])
+                    cat_v = np.concatenate([uv, self._mt_vals])
+                    cat_t = np.concatenate([ut, self._mt_tombs])
+                    mk, fi = np.unique(cat_k, return_index=True)
+                    self._mt_keys, self._mt_vals = mk, cat_v[fi]
+                    self._mt_tombs = cat_t[fi]
+                else:
+                    # big memtable, small batch: overwrite hits in place and
+                    # splice misses by position — O(batch log + memtable),
+                    # no full re-sort. Open snapshots hold COPIES of these
+                    # arrays and concurrent readers resolve the overlay
+                    # entirely under _mu, so the in-place writes never leak
+                    # into any view.
+                    pos = np.searchsorted(self._mt_keys, uk)
+                    pos_c = np.minimum(pos, m - 1)
+                    hit = self._mt_keys[pos_c] == uk
+                    self._mt_vals[pos_c[hit]] = uv[hit]
+                    self._mt_tombs[pos_c[hit]] = tombs
+                    if (~hit).any():
+                        self._mt_keys = np.insert(self._mt_keys, pos[~hit],
+                                                  uk[~hit])
+                        self._mt_vals = np.insert(self._mt_vals, pos[~hit],
+                                                  uv[~hit])
+                        self._mt_tombs = np.insert(self._mt_tombs,
+                                                   pos[~hit], tombs)
+                        acc["memtable_rows_spliced"] = m
+                over = len(self._mt_keys) >= self.memtable_capacity
+        finally:
+            self._mu.release()
+        acc.update(put_mu_wait_ns=t1 - t0, merge_ns=clock() - t1)
         if over:            # flush takes _wl (and may stall) — not under _mu
             self.flush()
 
@@ -348,9 +421,15 @@ class LsmStore:
                   else np.asarray(values, dtype=np.uint64))
         if len(keys) != len(values):
             raise ValueError("keys/values length mismatch")
-        self.stats.puts += len(keys)
-        if len(keys):
-            self._memtable_merge(keys, values, tombs=False)
+        acc = {"put_calls": 1, "puts": len(keys)}
+        t0 = clock()
+        try:
+            with span("lsm.put_batch", n=len(keys)):
+                if len(keys):
+                    self._memtable_merge(keys, values, False, acc)
+        finally:
+            acc["put_ns"] = clock() - t0
+            self.stats.add(acc)
 
     def put(self, key: int, value: int = 0) -> None:
         self.put_batch(np.array([key], np.uint64), np.array([value], np.uint64))
@@ -362,10 +441,13 @@ class LsmStore:
         a key that was never written is legal (a no-op once its tombstone is
         garbage-collected)."""
         keys = np.asarray(keys, dtype=np.uint64)
-        self.stats.deletes += len(keys)
-        if len(keys):
-            self._memtable_merge(keys, np.zeros(len(keys), dtype=np.uint64),
-                                 tombs=True)
+        acc = {"deletes": len(keys)}
+        try:
+            if len(keys):
+                self._memtable_merge(
+                    keys, np.zeros(len(keys), dtype=np.uint64), True, acc)
+        finally:
+            self.stats.add(acc)
 
     def delete(self, key: int) -> None:
         self.delete_batch(np.array([key], np.uint64))
@@ -396,27 +478,32 @@ class LsmStore:
         ``gone_keys`` (chained only) are keys with NO physical record left
         (GC'd tombstones) pinned as extra negatives, so "deleted keys never
         fire rebuilt filters" stays exact instead of false-positive-unlikely.
+        Its time and the layouts its stages tried are added to ``stats``.
         """
-        if self.filter_kind == "chained":
-            assert (len(dead_keys) == 0 or
-                    not np.intersect1d(live_keys, dead_keys).size), \
-                "tombstoned keys must never enroll as filter positives"
-            extra = [dead_keys] if len(dead_keys) else []
-            if gone_keys is not None and len(gone_keys):
-                extra.append(gone_keys)
-            other = (np.concatenate([other_keys, *extra]) if extra
-                     else other_keys)
-            return ChainedTableFilter.build(live_keys, other,
-                                            fp_alpha=self.fp_alpha,
-                                            seed1=seeds[0], seed2=seeds[1])
-        if self.filter_kind == "bloom":
-            if self.bits_per_key <= 0:
-                return None
-            fpr = max(1e-9, 2.0 ** (-self.bits_per_key * np.log(2)))
-            phys = (np.concatenate([live_keys, dead_keys])
-                    if len(dead_keys) else live_keys)
-            return BloomFilter.build(phys, float(fpr), seed=seeds[0])
-        return None
+        f = None
+        t0 = clock()
+        with span("lsm.filter_build", n=len(live_keys)):
+            if self.filter_kind == "chained":
+                assert (len(dead_keys) == 0 or
+                        not np.intersect1d(live_keys, dead_keys).size), \
+                    "tombstoned keys must never enroll as filter positives"
+                extra = [dead_keys] if len(dead_keys) else []
+                if gone_keys is not None and len(gone_keys):
+                    extra.append(gone_keys)
+                other = (np.concatenate([other_keys, *extra]) if extra
+                         else other_keys)
+                f = ChainedTableFilter.build(live_keys, other,
+                                             fp_alpha=self.fp_alpha,
+                                             seed1=seeds[0], seed2=seeds[1])
+            elif self.filter_kind == "bloom" and self.bits_per_key > 0:
+                fpr = max(1e-9, 2.0 ** (-self.bits_per_key * np.log(2)))
+                phys = (np.concatenate([live_keys, dead_keys])
+                        if len(dead_keys) else live_keys)
+                f = BloomFilter.build(phys, float(fpr), seed=seeds[0])
+        self.stats.add({"filter_build_ns": clock() - t0,
+                        "filter_build_attempts":
+                            0 if f is None else _layout_attempts(f)})
+        return f
 
     def _admit(self, bg) -> None:
         """Admission control (background mode only): block — bounded by
@@ -433,23 +520,25 @@ class LsmStore:
             t0 = time.monotonic()
             deadline = t0 + self.stall_timeout_s
             try:
-                while len(self.sstables) >= self.table_cap:
-                    bg.kick()
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        self.stats.stall_timeouts += 1
-                        raise WriteStall(
-                            f"write stalled {self.stall_timeout_s:.3f}s at "
-                            f"{len(self.sstables)} SSTables (cap "
-                            f"{self.table_cap}) — background compaction made "
-                            "no headroom; call compact() or back off",
-                            n_tables=len(self.sstables),
-                            waited_s=time.monotonic() - t0)
-                    self._stall_cv.wait(min(remaining, 0.05))
+                with span("lsm.stall"):
+                    while len(self.sstables) >= self.table_cap:
+                        bg.kick()
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0:
+                            self.stats.stall_timeouts += 1
+                            raise WriteStall(
+                                f"write stalled {self.stall_timeout_s:.3f}s at "
+                                f"{len(self.sstables)} SSTables (cap "
+                                f"{self.table_cap}) — background compaction made "
+                                "no headroom; call compact() or back off",
+                                n_tables=len(self.sstables),
+                                waited_s=time.monotonic() - t0)
+                        self._stall_cv.wait(min(remaining, 0.05))
             finally:
                 self._stall_waiters -= 1
                 self.stats.stall_time_s += time.monotonic() - t0
 
+    @traced("lsm.flush")
     def flush(self) -> None:
         """Freeze the memtable into the newest SSTable, build its filter
         (live keys only), exclude its keys from older chained filters online
@@ -581,6 +670,7 @@ class LsmStore:
                 return i, j
         return None
 
+    @traced("lsm.compact.merge")
     def _merge_run(self, tables: list, filters: list, i: int, j: int,
                    tomb_shadowing: np.ndarray | None = None
                    ) -> tuple[list, list]:
@@ -702,41 +792,45 @@ class LsmStore:
         pinned snapshots and in-flight probe streams are never torn.
         Installing notifies admission-stalled writers; hooks run after the
         swap, failure-isolated (``_run_publish_hooks``)."""
-        tables_bs, filters_bs = self.sstables, self.filters
-        live = [f for f in filters_bs if f is not None]
-        bank_state = None
-        if not live:
-            self.service = None
-            chains = tuple(("always",) for _ in tables_bs)
-            tables = np.zeros(TABLE_ALIGN, dtype=np.uint32)
-        else:
-            if len(live) != len(tables_bs):
-                raise RuntimeError("mixed filtered/filterless tables unsupported")
-            if self.service is None:
-                self.service = FilterService(live, mesh=self.mesh)
-            elif len(live) != self.service.bank.n_filters:
-                # filter added/removed: layouts certainly changed — skip the
-                # refresh_tables attempt (it would pack the whole bank once
-                # just to find out)
-                self.service.rebuild(live)
+        t0 = clock()
+        with span("lsm.publish", gen=self._next_gen_id):
+            tables_bs, filters_bs = self.sstables, self.filters
+            live = [f for f in filters_bs if f is not None]
+            bank_state = None
+            if not live:
+                self.service = None
+                chains = tuple(("always",) for _ in tables_bs)
+                tables = np.zeros(TABLE_ALIGN, dtype=np.uint32)
             else:
-                try:
-                    self.service.refresh_tables(live)
-                except ValueError:
+                if len(live) != len(tables_bs):
+                    raise RuntimeError("mixed filtered/filterless tables unsupported")
+                if self.service is None:
+                    self.service = FilterService(live, mesh=self.mesh)
+                elif len(live) != self.service.bank.n_filters:
+                    # filter added/removed: layouts certainly changed — skip the
+                    # refresh_tables attempt (it would pack the whole bank once
+                    # just to find out)
                     self.service.rebuild(live)
-            bank_state = self.service.state
-            chains = tuple(_chain_descriptor(lay)
-                           for lay in bank_state.bank.layouts)
-            tables = bank_state.bank.tables
-        gen = Generation.create(
-            self._next_gen_id, tables_bs, chains, tables, bank_state,
-            sum(f.bits for f in live))
-        with self._mu:
-            self._gen = gen
-            self._next_gen_id += 1
-            self.stats.generations_published += 1
-            self._stall_cv.notify_all()   # headroom may have appeared
-        self._run_publish_hooks(gen)
+                else:
+                    try:
+                        self.service.refresh_tables(live)
+                    except ValueError:
+                        self.service.rebuild(live)
+                bank_state = self.service.state
+                chains = tuple(_chain_descriptor(lay)
+                               for lay in bank_state.bank.layouts)
+                tables = bank_state.bank.tables
+            gen = Generation.create(
+                self._next_gen_id, tables_bs, chains, tables, bank_state,
+                sum(f.bits for f in live))
+            with self._mu:
+                self._gen = gen
+                self._next_gen_id += 1
+                self.stats.generations_published += 1
+                self._stall_cv.notify_all()   # headroom may have appeared
+        self.stats.add({"publish_ns": clock() - t0})
+        with span("lsm.publish.hooks"):
+            self._run_publish_hooks(gen)
 
     def _run_publish_hooks(self, gen: Generation) -> None:
         """Run every publish hook against the just-installed generation,
@@ -894,13 +988,15 @@ class LsmStore:
         [0, N], hits_mask int32 [n]); first_hit == N means no filter fired."""
         return self._gen.probe_batch(keys)
 
-    def _resolve_chained(self, stats, sstables, keys, first, found, vals,
-                         reads, idx):
+    @staticmethod
+    def _resolve_chained(sstables, keys, first, found, vals, reads, idx
+                         ) -> tuple[int, int]:
         """Chain rule (Fig 11b): read ONLY the newest-first first hit; a miss
         there proves every other fired filter is a false positive too.
         Tombstone records never fire chained filters (they are excluded at
         build and by ``exclude_deleted``), but a read landing on one is
-        still resolved as a miss — the key is deleted."""
+        still resolved as a miss — the key is deleted. Returns (SSTable
+        reads, wasted reads)."""
         n_tables = len(sstables)
         hit = first < n_tables
         reads[idx[hit]] = 1
@@ -909,65 +1005,125 @@ class LsmStore:
             live, v, _dead = sstables[int(t)].get_many(keys[sel])
             found[idx[sel]] = live
             vals[idx[sel]] = v
-        stats.sstable_reads += int(hit.sum())
-        stats.wasted_reads += int(hit.sum() - found[idx].sum())
+        n_reads = int(hit.sum())
+        return n_reads, n_reads - int(found[idx].sum())
 
-    def _resolve_masked(self, stats, sstables, keys, mask, found, vals,
-                        reads, idx):
+    @staticmethod
+    def _resolve_masked(sstables, keys, mask, found, vals, reads, idx
+                        ) -> tuple[int, int]:
         """Baseline policy (per-table Bloom / no filter): read EVERY fired
         table newest→oldest until the key's newest record turns up — live
-        (found) or tombstone (deleted; STOP, older versions are shadowed)."""
+        (found) or tombstone (deleted; STOP, older versions are shadowed).
+        Returns (SSTable reads, wasted reads)."""
         alive = np.ones(len(keys), dtype=bool)
+        n_reads = n_wasted = 0
         for t in range(len(sstables)):
             cand = alive & (((mask >> t) & 1) == 1)
             if not cand.any():
                 continue
             reads[idx[cand]] += 1
-            stats.sstable_reads += int(cand.sum())
+            n_reads += int(cand.sum())
             live, v, dead = sstables[t].get_many(keys[cand])
             hit_idx = idx[cand][live]
             found[hit_idx] = True
             vals[hit_idx] = v[live]
             resolved = live | dead
-            stats.wasted_reads += int((~live).sum())
+            n_wasted += int((~live).sum())
             alive[cand] &= ~resolved
+        return n_reads, n_wasted
 
     @staticmethod
     def _overlay_resolve(mt_keys, mt_vals, mt_tombs, keys, found, vals,
-                         resolved, stats: StoreStats) -> None:
+                         resolved) -> int:
         """Resolve a key batch against ONE sorted (keys, vals, tombs)
         overlay run, in place. Entries a NEWER overlay already resolved are
         skipped (newest wins); a tombstone RESOLVES its key (deleted, 0
         reads) — it must not fall through to the SSTables, whose stale
-        versions it shadows; live hits resolve as found."""
+        versions it shadows; live hits resolve as found. Returns the keys
+        the run resolved (memtable hits)."""
         if not len(mt_keys):
-            return
+            return 0
         pos = np.minimum(np.searchsorted(mt_keys, keys), len(mt_keys) - 1)
         inmem = (mt_keys[pos] == keys) & ~resolved
         live = inmem & ~mt_tombs[pos]
         vals[live] = mt_vals[pos[live]]
         found |= live
         resolved |= inmem
-        stats.memtable_hits += int(inmem.sum())
+        return int(inmem.sum())
 
-    def _gen_resolve(self, gen: Generation, keys, found, vals, reads,
-                     resolved, stats: StoreStats) -> None:
-        """Resolve the overlay leftovers against one immutable generation:
-        ONE fused probe launch, then the policy resolver. Lock-free — the
-        generation's buffers are frozen at publish."""
-        rest = ~resolved
-        if not rest.any() or not gen.sstables:
-            return
-        idx = np.flatnonzero(rest)
+    def _gen_resolve(self, gen: Generation, keys, idx, found, vals, reads,
+                     acc: dict) -> None:
+        """Resolve the overlay leftovers ``keys[idx]`` against one immutable
+        generation: ONE fused probe launch, then the policy resolver.
+        Lock-free — the generation's buffers are frozen at publish."""
         sub = keys[idx]
-        stats.probed += len(sub)
-        first, mask = gen.probe_batch(sub)
-        if self.filter_kind == "chained":
-            self._resolve_chained(stats, gen.sstables, sub, first, found,
-                                  vals, reads, idx)
-        else:
-            self._resolve_masked(stats, gen.sstables, sub, mask, found,
-                                 vals, reads, idx)
+        acc["probed"] = len(sub)
+        first, mask = gen.probe_batch(sub, acc)
+        t0 = clock()
+        with span("lsm.resolve"):
+            if self.filter_kind == "chained":
+                n_reads, n_wasted = self._resolve_chained(
+                    gen.sstables, sub, first, found, vals, reads, idx)
+            else:
+                n_reads, n_wasted = self._resolve_masked(
+                    gen.sstables, sub, mask, found, vals, reads, idx)
+        acc.update(resolve_ns=clock() - t0, sstable_reads=n_reads,
+                   wasted_reads=n_wasted)
+
+    def _read(self, keys: np.ndarray, stats: StoreStats, view=None
+              ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """One batched read (``lsm.get_batch``): the overlay, then the
+        generation's probe and resolve; the call's counts go to ``stats``
+        in one update. ``view`` is a pinned (generation, memtable keys,
+        values, tombstones); without one the read overlays the live
+        memtable and any flushing run under the small lock, and captures
+        the generation in the same critical section."""
+        keys = np.asarray(keys, dtype=np.uint64)
+        n = len(keys)
+        found = np.zeros(n, dtype=bool)
+        vals = np.zeros(n, dtype=np.uint64)
+        reads = np.zeros(n, dtype=np.int32)
+        resolved = np.zeros(n, dtype=bool)
+        acc = {"get_calls": 1, "gets": n}
+        cpu0 = (time.thread_time_ns()
+                if next(self._reads) % CPU_SAMPLE == 0 else None)
+        t0 = clock()
+        try:
+            with span("lsm.get_batch", n=n):
+                if view is None:
+                    with span("lsm.get.mu_wait"):
+                        self._mu.acquire()
+                t1 = clock()
+                with span("lsm.overlay"):
+                    if view is not None:
+                        gen = view[0]
+                        hits = self._overlay_resolve(*view[1:], keys, found,
+                                                     vals, resolved)
+                    else:
+                        try:
+                            gen = self._gen
+                            hits = self._overlay_resolve(
+                                self._mt_keys, self._mt_vals, self._mt_tombs,
+                                keys, found, vals, resolved)
+                            if self._fl_keys is not None:
+                                hits += self._overlay_resolve(
+                                    self._fl_keys, self._fl_vals,
+                                    self._fl_tombs, keys, found, vals,
+                                    resolved)
+                        finally:
+                            self._mu.release()
+                    idx = np.flatnonzero(~resolved)    # keys left to probe
+                acc.update(get_mu_wait_ns=t1 - t0, overlay_ns=clock() - t1,
+                           memtable_hits=hits)
+                if len(idx) and gen.sstables:
+                    self._gen_resolve(gen, keys, idx, found, vals, reads, acc)
+        finally:
+            acc["get_ns"] = clock() - t0
+            if cpu0 is not None:
+                acc["get_cpu_calls"] = 1
+                acc["get_cpu_ns"] = time.thread_time_ns() - cpu0
+            stats.add(acc)
+        return found, vals, reads
 
     def _view_get_batch(self, gen: Generation, mt_keys, mt_vals, mt_tombs,
                         keys: np.ndarray, stats: StoreStats
@@ -978,19 +1134,7 @@ class LsmStore:
         white-box single-view probes. Live reads go through ``get_batch``,
         which overlays the mutable memtable (and any flushing run) under
         the small lock first."""
-        keys = np.asarray(keys, dtype=np.uint64)
-        n = len(keys)
-        found = np.zeros(n, dtype=bool)
-        vals = np.zeros(n, dtype=np.uint64)
-        reads = np.zeros(n, dtype=np.int32)
-        stats.gets += n
-        if n == 0:
-            return found, vals, reads
-        resolved = np.zeros(n, dtype=bool)
-        self._overlay_resolve(mt_keys, mt_vals, mt_tombs, keys, found, vals,
-                              resolved, stats)
-        self._gen_resolve(gen, keys, found, vals, reads, resolved, stats)
-        return found, vals, reads
+        return self._read(keys, stats, (gen, mt_keys, mt_vals, mt_tombs))
 
     def get_batch(self, keys: np.ndarray
                   ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -1003,27 +1147,7 @@ class LsmStore:
         section, so a publish racing this call can never tear the probe
         across two bank versions; the probe itself runs lock-free against
         the captured generation's frozen buffers."""
-        keys = np.asarray(keys, dtype=np.uint64)
-        n = len(keys)
-        found = np.zeros(n, dtype=bool)
-        vals = np.zeros(n, dtype=np.uint64)
-        reads = np.zeros(n, dtype=np.int32)
-        resolved = np.zeros(n, dtype=bool)
-        with self._mu:
-            gen = self._gen
-            self.stats.gets += n
-            if n:
-                self._overlay_resolve(self._mt_keys, self._mt_vals,
-                                      self._mt_tombs, keys, found, vals,
-                                      resolved, self.stats)
-                if self._fl_keys is not None:
-                    self._overlay_resolve(self._fl_keys, self._fl_vals,
-                                          self._fl_tombs, keys, found, vals,
-                                          resolved, self.stats)
-        if n:
-            self._gen_resolve(gen, keys, found, vals, reads, resolved,
-                              self.stats)
-        return found, vals, reads
+        return self._read(keys, self.stats)
 
     def get(self, key: int) -> tuple[bool, int, int]:
         """(found, value, reads) for one key."""
@@ -1237,6 +1361,7 @@ class LsmStore:
         bg = self._bg
         return [] if bg is None else list(bg.errors)
 
+    @traced("lsm.bg_step")
     def _background_step(self) -> bool:
         """ONE unit of background work under the mutator lock — a deferred
         GC sweep if one is runnable, else a single merge run (size-tiered
