@@ -6,19 +6,22 @@ latency vs Bloom filters at equal space (Fig 12). ``core.lsm`` models one
 level per-key on the host; this module is the serving-scale engine on top
 of the PR-1 probe stack:
 
-- **Write path.** ``put_batch`` merges each batch into a sorted-array
-  memtable (newest-wins, one vectorized merge — no Python dict); ``flush``
-  freezes it into the newest immutable ``SSTable`` and builds that table's
-  two-stage ChainedFilter (stage-1 Xor, stage-2 dynamic Othello —
-  ``core.lsm.ChainedTableFilter``, the same construction and seed schedule
-  as ``LsmLevelChained``, so a store and the host model fed the same flush
-  sequence are bit-identical). Both filter stages build as bulk array
-  passes (Bloomier peeling / Othello bipartite peeling), and older tables'
-  filters exclude the new keys online (§5.4.3) with ONE batched union-find
-  pass per table instead of per-key component walks. Size-tiered
-  compaction merges age-adjacent runs of similar size and rebuilds ONLY
-  the merged table's filter, with negatives drawn from every other table
-  so per-table exactness over the store's key universe survives.
+- **Write path.** ``put_batch`` merges each batch into a memtable of
+  immutable sorted runs (newest-wins, vectorized merges by position — no
+  Python dict): a small batch splices into a small delta run under the
+  small lock, and a full delta folds into the base run outside it;
+  ``flush`` drains the runs into the newest immutable ``SSTable`` and
+  builds that table's two-stage ChainedFilter (stage-1 Xor, stage-2
+  dynamic Othello — ``core.lsm.ChainedTableFilter``, the same construction
+  and seed schedule as ``LsmLevelChained``, so a store and the host model
+  fed the same flush sequence are bit-identical). Both filter stages build
+  as bulk array passes (Bloomier peeling / Othello bipartite peeling), and
+  older tables' filters exclude the new keys online (§5.4.3) with ONE
+  batched union-find pass per table instead of per-key component walks.
+  Size-tiered compaction merges age-adjacent runs of similar size and
+  rebuilds ONLY the merged table's filter, with negatives drawn from every
+  other table so per-table exactness over the store's key universe
+  survives.
 
 - **Read path: generations.** Every flush/compaction/deferred-GC sweep
   funnels through ONE swap point (``_publish``): the build-side
@@ -83,6 +86,58 @@ FILTER_KINDS = ("chained", "bloom", "none")
 # thread CPU time is a system call (~6 µs on a v5e host, against 0.08 µs for
 # the wall clock), so reads take it on one call in this many
 CPU_SAMPLE = 16
+# a memtable of fewer keys takes every batch into its base run; past it,
+# small batches splice into a delta run, and a writer that takes the delta
+# to this many rows seals it for a fold into the base
+DELTA_ROWS = 16384
+
+
+def _frozen(keys: np.ndarray, vals: np.ndarray, tombs: np.ndarray) -> tuple:
+    """One memtable run: sorted, deduplicated (keys, vals, tombs), marked
+    read-only — a run is replaced, never written."""
+    for a in (keys, vals, tombs):
+        a.setflags(write=False)
+    return keys, vals, tombs
+
+
+_EMPTY_RUN = _frozen(np.empty(0, np.uint64), np.empty(0, np.uint64),
+                     np.empty(0, bool))
+
+
+def _merge_runs(new: tuple, old: tuple) -> tuple:
+    """Newest-wins union of two runs, ``new`` shadowing ``old``, in one
+    linear pass by position (no sort): each new row lands at its
+    ``searchsorted`` slot in ``old``, shifted by the new keys inserted
+    before it — onto the old row of its key where there is one — and the
+    old rows fill the slots left."""
+    nk, ok = new[0], old[0]
+    if not len(ok):
+        return new
+    if not len(nk):
+        return old
+    pos = np.searchsorted(ok, nk)
+    miss = ok[np.minimum(pos, len(ok) - 1)] != nk
+    dst = pos + (np.cumsum(miss) - miss)
+    n = len(ok) + int(np.count_nonzero(miss))
+    from_old = np.ones(n, dtype=bool)
+    from_old[dst[miss]] = False
+    out = []
+    for a_new, a_old in zip(new, old):
+        o = np.empty(n, a_new.dtype)
+        o[from_old] = a_old
+        o[dst] = a_new
+        out.append(o)
+    return _frozen(*out)
+
+
+def _merge_all(runs) -> tuple:
+    """One run from ``runs`` given newest first (``None`` entries skipped);
+    no copy when a single run holds rows."""
+    out = _EMPTY_RUN
+    for run in reversed(runs):
+        if run is not None:
+            out = _merge_runs(run, out)
+    return out
 
 
 class WriteStall(RuntimeError):
@@ -226,7 +281,10 @@ class StoreStats:
     put_ns: int = 0
     put_mu_wait_ns: int = 0          # lsm.put.mu_wait: acquiring _mu
     merge_ns: int = 0                # lsm.memtable.merge, under _mu
-    memtable_rows_spliced: int = 0   # memtable rows copied by np.insert
+    memtable_rows_spliced: int = 0   # delta rows copied under _mu
+    memtable_folds: int = 0          # lsm.memtable.fold, outside _mu
+    fold_ns: int = 0
+    fold_rows: int = 0               # rows of the base runs folds wrote
     # builds
     filter_build_ns: int = 0         # lsm.filter_build
     filter_build_attempts: int = 0   # layouts tried, every stage of a filter
@@ -289,10 +347,10 @@ class LsmStore:
         self._flush_count = 0
         self._compact_count = 0
         # two-lock protocol (lock order: _wl then _mu, never the reverse):
-        # - _mu is the SMALL lock — memtable/flushing arrays, the _gen swap,
-        #   snapshot bookkeeping and stall signalling. Readers take only _mu
-        #   and only briefly (overlay resolution / part slicing); generation
-        #   probing runs lock-free against immutable state.
+        # - _mu is the SMALL lock — the memtable and flushing run references,
+        #   the _gen swap, snapshot bookkeeping and stall signalling. Readers
+        #   take only _mu and only to capture references; the overlay and
+        #   generation probing run lock-free against immutable state.
         # - _wl is the MUTATOR lock — serializes flush / compaction / GC
         #   sweeps, so build-side list edits and in-place filter exclusions
         #   never interleave. Readers never take it; the background
@@ -315,106 +373,127 @@ class LsmStore:
         self._snapshots: list[Snapshot] = []      # open handles, any order
         self._pinned: dict[int, int] = {}         # gen_id -> snapshot refs
         self._gc_pending = False                  # deferred tombstones exist
-        # array-backed memtable: parallel sorted key/value/tombstone arrays,
-        # merged on every put_batch/delete_batch (newest-wins) — flush drains
-        # them with zero copies. A True tombstone row means "deleted here".
-        self._mt_keys = np.empty(0, dtype=np.uint64)
-        self._mt_vals = np.empty(0, dtype=np.uint64)
-        self._mt_tombs = np.empty(0, dtype=bool)
+        # memtable: up to three immutable runs (``_frozen``), newest first —
+        # ``_delta``, which small batches splice into under _mu (O(delta));
+        # ``_sealed``, a delta being folded into the base outside _mu, or
+        # None; and ``_base``. Writers replace runs, never write them, so _mu
+        # guards only the references. A True tombstone row means "deleted
+        # here". ``_mt_len`` counts the distinct keys across the three.
+        self._delta = self._base = _EMPTY_RUN
+        self._sealed = None
+        self._mt_len = 0
         # FLUSHING slot (LevelDB's immutable memtable): flush moves the
-        # drained arrays here so readers keep resolving them — memtable →
+        # drained run here so readers keep resolving it — memtable →
         # flushing → generation, newest wins — for the whole filter build,
-        # then the publish that installs the table clears the slot. Frozen
-        # (read-only) while occupied; None otherwise.
-        self._fl_keys = None
-        self._fl_vals = None
-        self._fl_tombs = None
+        # then the publish that installs the table clears the slot. A
+        # (keys, vals, tombs) run while occupied; None otherwise.
+        self._flushing = None
 
     @property
     def memtable_len(self) -> int:
         """Records not yet in a published SSTable: live memtable plus any
         in-flight flushing run (the write queue depth)."""
         with self._mu:
-            fl = 0 if self._fl_keys is None else len(self._fl_keys)
-            return len(self._mt_keys) + fl
+            return self._queue_depth()
+
+    def _queue_depth(self) -> int:
+        fl = 0 if self._flushing is None else len(self._flushing[0])
+        return self._mt_len + fl
+
+    def _memtable_runs(self) -> list:
+        """The memtable's runs and any flushing run that hold rows, newest
+        first. Caller holds _mu; the runs themselves are immutable."""
+        return [r for r in (self._delta, self._sealed, self._base,
+                            self._flushing) if r is not None and len(r[0])]
 
     @property
     def memtable(self) -> "types.MappingProxyType":
-        """Read-only dict view of the sorted-array memtable's LIVE entries
-        — any in-flight flushing run folded underneath (memtable newer) —
+        """Read-only dict view of the memtable's LIVE entries — any
+        in-flight flushing run merged underneath (memtable newer) —
         (debugging / introspection; mutation raises — write through
         ``put_batch``/``delete_batch``)."""
         with self._mu:
-            if self._fl_keys is not None and len(self._fl_keys):
-                cat_k = np.concatenate([self._mt_keys, self._fl_keys])
-                cat_v = np.concatenate([self._mt_vals, self._fl_vals])
-                cat_t = np.concatenate([self._mt_tombs, self._fl_tombs])
-                ks, fi = np.unique(cat_k, return_index=True)
-                vs, ts = cat_v[fi], cat_t[fi]
-            else:
-                ks, vs, ts = self._mt_keys, self._mt_vals, self._mt_tombs
-            live = ~ts
-            return types.MappingProxyType(
-                dict(zip(ks[live].tolist(), vs[live].tolist())))
+            runs = self._memtable_runs()
+        ks, vs, ts = _merge_all(runs)
+        live = ~ts
+        return types.MappingProxyType(
+            dict(zip(ks[live].tolist(), vs[live].tolist())))
 
     # ------------------------------------------------------------- write path
     def _memtable_merge(self, keys: np.ndarray, values: np.ndarray,
                         tombs: bool, acc: dict) -> None:
         """Newest-wins merge of one (deduped-last) record batch into the
-        sorted array memtable; ``tombs`` marks the whole batch as tombstones
-        (deletes) or live (puts). Lock wait, merge time and spliced rows
-        are set in the call's counts ``acc``."""
+        memtable; ``tombs`` marks the whole batch as tombstones (deletes)
+        or live (puts).
+
+        Into a memtable of fewer than ``DELTA_ROWS`` keys, or with a batch
+        of at least an eighth of it (a bulk load), the batch and every run
+        merge into a new base. Any other batch splices into the delta, by
+        position: O(delta) under _mu, never O(memtable). The writer that
+        takes the delta to ``DELTA_ROWS`` rows seals it, unless a fold is
+        already running, and folds it into the base after releasing _mu
+        (``_fold``); writers meanwhile keep splicing into a fresh delta.
+        Lock wait, merge time, delta rows spliced and the fold's counts are
+        set in the call's counts ``acc``."""
         # dedupe within the batch (reversed + unique keeps the LAST write)
         uk, first_idx = np.unique(keys[::-1], return_index=True)
-        uv = values[::-1][first_idx]
-        ut = np.full(len(uk), tombs, dtype=bool)
+        batch = _frozen(uk, values[::-1][first_idx],
+                        np.full(len(uk), tombs, dtype=bool))
+        fold = None
         t0 = clock()
         with span("lsm.put.mu_wait"):
             self._mu.acquire()
         t1 = clock()
         try:
             with span("lsm.memtable.merge", n=len(uk)):
-                m = len(self._mt_keys)
-                if m < 16384 or len(uk) * 8 >= m:
-                    # small memtable / large relative batch: one combined
-                    # unique (newest occurrence first ⇒ batch shadows old)
-                    cat_k = np.concatenate([uk, self._mt_keys])
-                    cat_v = np.concatenate([uv, self._mt_vals])
-                    cat_t = np.concatenate([ut, self._mt_tombs])
-                    mk, fi = np.unique(cat_k, return_index=True)
-                    self._mt_keys, self._mt_vals = mk, cat_v[fi]
-                    self._mt_tombs = cat_t[fi]
+                m = self._mt_len
+                if m < DELTA_ROWS or len(uk) * 8 >= m:
+                    self._base = _merge_all(
+                        [batch, self._delta, self._sealed, self._base])
+                    self._delta, self._sealed = _EMPTY_RUN, None
+                    self._mt_len = len(self._base[0])
                 else:
-                    # big memtable, small batch: overwrite hits in place and
-                    # splice misses by position — O(batch log + memtable),
-                    # no full re-sort. Open snapshots hold COPIES of these
-                    # arrays and concurrent readers resolve the overlay
-                    # entirely under _mu, so the in-place writes never leak
-                    # into any view.
-                    pos = np.searchsorted(self._mt_keys, uk)
-                    pos_c = np.minimum(pos, m - 1)
-                    hit = self._mt_keys[pos_c] == uk
-                    self._mt_vals[pos_c[hit]] = uv[hit]
-                    self._mt_tombs[pos_c[hit]] = tombs
-                    if (~hit).any():
-                        self._mt_keys = np.insert(self._mt_keys, pos[~hit],
-                                                  uk[~hit])
-                        self._mt_vals = np.insert(self._mt_vals, pos[~hit],
-                                                  uv[~hit])
-                        self._mt_tombs = np.insert(self._mt_tombs,
-                                                   pos[~hit], tombs)
-                        acc["memtable_rows_spliced"] = m
-                over = len(self._mt_keys) >= self.memtable_capacity
+                    # keys new to every run keep memtable_len exact
+                    fresh = ~_in_sorted(self._delta[0], uk)
+                    for run in (self._sealed, self._base):
+                        if run is not None:
+                            fresh &= ~_in_sorted(run[0], uk)
+                    self._mt_len += int(np.count_nonzero(fresh))
+                    acc["memtable_rows_spliced"] = len(self._delta[0])
+                    delta = _merge_runs(batch, self._delta)
+                    if len(delta[0]) >= DELTA_ROWS and self._sealed is None:
+                        fold = (delta, self._base)
+                        self._sealed, self._delta = delta, _EMPTY_RUN
+                    else:
+                        self._delta = delta
+                over = self._mt_len >= self.memtable_capacity
         finally:
             self._mu.release()
         acc.update(put_mu_wait_ns=t1 - t0, merge_ns=clock() - t1)
+        if fold is not None:
+            self._fold(*fold, acc)
         if over:            # flush takes _wl (and may stall) — not under _mu
             self.flush()
+
+    def _fold(self, sealed: tuple, base: tuple, acc: dict) -> None:
+        """Merge a sealed delta into the base outside the small lock, then
+        swap the result in under it — unless a flush or a bulk merge took
+        either run meanwhile: their rows are then already in the run that
+        replaced them, and the result is dropped. Time and rows written are
+        set in ``acc``."""
+        t0 = clock()
+        with span("lsm.memtable.fold", n=len(sealed[0])):
+            merged = _merge_runs(sealed, base)
+            with self._mu:
+                if self._sealed is sealed and self._base is base:
+                    self._base, self._sealed = merged, None
+        acc.update(memtable_folds=1, fold_ns=clock() - t0,
+                   fold_rows=len(merged[0]))
 
     def put_batch(self, keys: np.ndarray, values: np.ndarray | None = None
                   ) -> None:
         """Upsert a key batch (newest write wins): one vectorized sorted
-        merge into the array memtable. Auto-flushes whenever the memtable
+        merge into the memtable's runs. Auto-flushes whenever the memtable
         reaches capacity."""
         keys = np.asarray(keys, dtype=np.uint64)
         values = (np.zeros(len(keys), dtype=np.uint64) if values is None
@@ -558,7 +637,7 @@ class LsmStore:
         build-side state then raises the (now typed) ``WriteStall``."""
         while True:
             with self._mu:
-                if not len(self._mt_keys):
+                if not self._mt_len:
                     return
             bg = self._bg
             bg_active = bg is not None and bg.running
@@ -573,17 +652,18 @@ class LsmStore:
     def _flush_locked(self, bg_active: bool) -> None:
         """The flush body, under the mutator lock ``_wl``."""
         with self._mu:
-            if not len(self._mt_keys):
+            if not self._mt_len:
                 return
-            # the array memtable IS the sorted, deduped run — drain it into
-            # the flushing slot (readers resolve it there until the publish)
-            keys, vals, tombs = self._mt_keys, self._mt_vals, self._mt_tombs
-            self._fl_keys, self._fl_vals, self._fl_tombs = keys, vals, tombs
-            self._mt_keys = np.empty(0, dtype=np.uint64)
-            self._mt_vals = np.empty(0, dtype=np.uint64)
-            self._mt_tombs = np.empty(0, dtype=bool)
-        for a in (keys, vals, tombs):
-            a.setflags(write=False)       # frozen while readers overlay them
+            # drain the memtable's runs, merged newest-wins into one sorted,
+            # deduped run (no copy when the base alone holds rows, as after
+            # a bulk load), into the flushing slot: readers resolve it there
+            # until the publish. A fold still running finds its runs gone
+            # and drops its result.
+            keys, vals, tombs = self._flushing = _merge_all(
+                [self._delta, self._sealed, self._base])
+            self._delta = self._base = _EMPTY_RUN
+            self._sealed = None
+            self._mt_len = 0
         try:
             if tombs.any():
                 # flush-time GC: a tombstone only earns its SSTable row if
@@ -648,7 +728,7 @@ class LsmStore:
             # failed and the records are in the build-side lists / lost to
             # the error) — either way the overlay slot retires
             with self._mu:
-                self._fl_keys = self._fl_vals = self._fl_tombs = None
+                self._flushing = None
 
     # ------------------------------------------------------------- compaction
     def _find_run(self, tables: list) -> tuple[int, int] | None:
@@ -876,25 +956,14 @@ class LsmStore:
     def snapshot(self) -> Snapshot:
         """Open a pinned point-in-time read handle: the current generation
         (refcounted — compaction may neither mutate nor free its tables)
-        plus a frozen copy of the memtable. Close it (or use ``with``) to
+        plus a frozen image of the memtable. Close it (or use ``with``) to
         release; GC of tombstones the snapshot still observes is deferred
-        until then. Atomic under the small lock: the frozen memtable image
-        (any in-flight flushing run folded underneath, memtable newer) and
-        the pinned generation are one consistent cut."""
+        until then. Atomic under the small lock: the memtable image (its
+        runs and any in-flight flushing run merged newest-wins into one)
+        and the pinned generation are one consistent cut."""
         with self._mu:
-            if self._fl_keys is not None and len(self._fl_keys):
-                cat_k = np.concatenate([self._mt_keys, self._fl_keys])
-                cat_v = np.concatenate([self._mt_vals, self._fl_vals])
-                cat_t = np.concatenate([self._mt_tombs, self._fl_tombs])
-                mt_k, fi = np.unique(cat_k, return_index=True)
-                mt_v, mt_t = cat_v[fi], cat_t[fi]
-            else:
-                mt_k, mt_v, mt_t = (self._mt_keys.copy(),
-                                    self._mt_vals.copy(),
-                                    self._mt_tombs.copy())
-            for a in (mt_k, mt_v, mt_t):
-                a.setflags(write=False)
-            snap = Snapshot(self, self._gen, mt_k, mt_v, mt_t)
+            snap = Snapshot(self, self._gen,
+                            *_merge_all(self._memtable_runs()))
             self._snapshots.append(snap)
             gid = self._gen.gen_id
             self._pinned[gid] = self._pinned.get(gid, 0) + 1
@@ -1074,10 +1143,10 @@ class LsmStore:
               ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One batched read (``lsm.get_batch``): the overlay, then the
         generation's probe and resolve; the call's counts go to ``stats``
-        in one update. ``view`` is a pinned (generation, memtable keys,
-        values, tombstones); without one the read overlays the live
-        memtable and any flushing run under the small lock, and captures
-        the generation in the same critical section."""
+        in one update. ``view`` is a pinned (generation, [memtable run]);
+        without one the read captures the generation and the memtable's
+        runs (and any flushing run) under the small lock, and overlays
+        them after releasing it."""
         keys = np.asarray(keys, dtype=np.uint64)
         n = len(keys)
         found = np.zeros(n, dtype=bool)
@@ -1095,23 +1164,17 @@ class LsmStore:
                         self._mu.acquire()
                 t1 = clock()
                 with span("lsm.overlay"):
-                    if view is not None:
-                        gen = view[0]
-                        hits = self._overlay_resolve(*view[1:], keys, found,
-                                                     vals, resolved)
-                    else:
+                    if view is None:
                         try:
-                            gen = self._gen
-                            hits = self._overlay_resolve(
-                                self._mt_keys, self._mt_vals, self._mt_tombs,
-                                keys, found, vals, resolved)
-                            if self._fl_keys is not None:
-                                hits += self._overlay_resolve(
-                                    self._fl_keys, self._fl_vals,
-                                    self._fl_tombs, keys, found, vals,
-                                    resolved)
+                            gen, runs = self._gen, self._memtable_runs()
                         finally:
                             self._mu.release()
+                    else:
+                        gen, runs = view
+                    hits = 0
+                    for run in runs:                    # newest first
+                        hits += self._overlay_resolve(*run, keys, found,
+                                                      vals, resolved)
                     idx = np.flatnonzero(~resolved)    # keys left to probe
                 acc.update(get_mu_wait_ns=t1 - t0, overlay_ns=clock() - t1,
                            memtable_hits=hits)
@@ -1130,23 +1193,22 @@ class LsmStore:
                         ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Batched point queries against ONE (generation, frozen memtable
         image) view — the resolution path for snapshot reads (pinned
-        generation + frozen copy, accounted in ``self.snap_stats``) and
+        generation + frozen image, accounted in ``self.snap_stats``) and
         white-box single-view probes. Live reads go through ``get_batch``,
-        which overlays the mutable memtable (and any flushing run) under
-        the small lock first."""
-        return self._read(keys, stats, (gen, mt_keys, mt_vals, mt_tombs))
+        which overlays the memtable's runs (and any flushing run)."""
+        return self._read(keys, stats, (gen, [(mt_keys, mt_vals, mt_tombs)]))
 
     def get_batch(self, keys: np.ndarray
                   ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Batched point queries -> (found bool [n], values uint64 [n],
         sstable_reads int32 [n]). Memtable hits cost 0 reads; with chained
         filters every other key costs ≤ 1 read (found or wasted). The
-        overlay resolution (memtable → flushing run, newest wins) completes
-        under the small lock — the in-place memtable merge can therefore
-        never tear it — and the generation is captured in the same critical
-        section, so a publish racing this call can never tear the probe
-        across two bank versions; the probe itself runs lock-free against
-        the captured generation's frozen buffers."""
+        memtable's runs, any flushing run and the generation are captured
+        in one critical section of the small lock — one consistent cut, so
+        a merge, fold, flush or publish racing this call can never tear
+        it. Every run is immutable, so the overlay (delta → sealed → base
+        → flushing, newest wins) and the probe run lock-free against what
+        was captured."""
         return self._read(keys, self.stats)
 
     def get(self, key: int) -> tuple[bool, int, int]:
@@ -1162,23 +1224,32 @@ class LsmStore:
             raise ValueError("scan bounds: 0 <= lo < 2**64, 0 <= hi <= 2**64")
         return lo_u, hi_u
 
-    def _scan_merge(self, gen: Generation, parts_k, parts_v, parts_t,
-                    lo_u: int, hi_u: int, stats: StoreStats
-                    ) -> tuple[np.ndarray, np.ndarray]:
-        """Slice every overlapping SSTable of ``gen`` (min/max fence
-        pruning) behind the overlay parts already collected (newest first),
-        then one ``np.unique`` newest-wins merge with tombstone masking.
-        Lock-free — the generation and its tables are immutable."""
+    def _scan_merge(self, gen: Generation, runs: list, lo: int, hi: int,
+                    stats: StoreStats) -> tuple[np.ndarray, np.ndarray]:
+        """Slice the overlay ``runs`` (newest first) and every overlapping
+        SSTable of ``gen`` (min/max fence pruning) over ``[lo, hi)``, then
+        one ``np.unique`` newest-wins merge with tombstone masking.
+        Lock-free — the runs, the generation and its tables are
+        immutable."""
+        lo_u, hi_u = self._check_scan_bounds(lo, hi)
+        stats.scans += 1
+        parts_k, parts_v, parts_t = [], [], []
         if lo_u < hi_u:
+            # a memtable run IS a sorted run — reuse the SSTable slicer
+            # (single home for the window-boundary logic, 2**64 incl.)
+            sources = [SSTable(*run) for run in runs if len(run[0])]
             for t in gen.sstables:                        # newest → oldest
                 if not t.overlaps_range(lo_u, hi_u):
                     stats.scan_tables_pruned += 1
                     continue
                 stats.scan_tables_read += 1
+                sources.append(t)
+            for t in sources:
                 ks, vs, ts = t.slice_range(lo_u, hi_u)
-                parts_k.append(ks)
-                parts_v.append(vs)
-                parts_t.append(ts)
+                if len(ks):
+                    parts_k.append(ks)
+                    parts_v.append(vs)
+                    parts_t.append(ts)
         if not parts_k:
             return np.empty(0, np.uint64), np.empty(0, np.uint64)
         cat_k = np.concatenate(parts_k)
@@ -1191,19 +1262,7 @@ class LsmStore:
                    ) -> tuple[np.ndarray, np.ndarray]:
         """Full-window k-way merge against ONE (generation, frozen memtable
         image) view — the snapshot scan path."""
-        lo_u, hi_u = self._check_scan_bounds(lo, hi)
-        stats.scans += 1
-        parts_k, parts_v, parts_t = [], [], []
-        if lo_u < hi_u and len(mt_keys):
-            # the memtable IS a sorted run — reuse the SSTable slicer
-            # (single home for the window-boundary logic, 2**64 incl.)
-            mt = SSTable(mt_keys, mt_vals, mt_tombs)
-            ks, vs, ts = mt.slice_range(lo_u, hi_u)
-            if len(ks):
-                parts_k.append(ks)
-                parts_v.append(vs)
-                parts_t.append(ts)
-        return self._scan_merge(gen, parts_k, parts_v, parts_t, lo_u, hi_u,
+        return self._scan_merge(gen, [(mt_keys, mt_vals, mt_tombs)], lo, hi,
                                 stats)
 
     def scan(self, lo: int, hi: int) -> tuple[np.ndarray, np.ndarray]:
@@ -1212,41 +1271,18 @@ class LsmStore:
         ``hi`` may be 2**64, so ``scan(0, 2**64)`` covers the whole key
         space including the maximum uint64 key.
 
-        K-way merge across memtable (+ any in-flight flushing run) + every
-        SSTable of the CURRENT generation with newest-wins / tombstone
-        masking: sources concatenate newest-first and one ``np.unique``
-        (keeps the FIRST = newest record per key) resolves shadowing, then
-        tombstoned survivors drop out. Filters cannot prune a range — a
-        window is not a key — but each sorted run's min/max fences can:
-        tables whose span misses the window are never sliced. The overlay
-        slices are cut (and, for the mutable memtable, copied) under the
-        small lock in the same critical section that captures the
-        generation; the table merge itself runs lock-free."""
-        lo_u, hi_u = self._check_scan_bounds(lo, hi)
-        parts_k, parts_v, parts_t = [], [], []
+        K-way merge across the memtable's runs (+ any in-flight flushing
+        run) + every SSTable of the CURRENT generation with newest-wins /
+        tombstone masking: sources concatenate newest-first and one
+        ``np.unique`` (keeps the FIRST = newest record per key) resolves
+        shadowing, then tombstoned survivors drop out. Filters cannot prune
+        a range — a window is not a key — but each sorted run's min/max
+        fences can: tables whose span misses the window are never sliced.
+        The runs and the generation are captured under the small lock in
+        one critical section; the slicing and merge run lock-free."""
         with self._mu:
-            gen = self._gen
-            self.stats.scans += 1
-            if lo_u < hi_u:
-                if len(self._mt_keys):
-                    mt = SSTable(self._mt_keys, self._mt_vals, self._mt_tombs)
-                    ks, vs, ts = mt.slice_range(lo_u, hi_u)
-                    if len(ks):
-                        # copies: slice_range returns views and the in-place
-                        # memtable merge may mutate the backing arrays the
-                        # moment the lock drops
-                        parts_k.append(ks.copy())
-                        parts_v.append(vs.copy())
-                        parts_t.append(ts.copy())
-                if self._fl_keys is not None and len(self._fl_keys):
-                    fl = SSTable(self._fl_keys, self._fl_vals, self._fl_tombs)
-                    ks, vs, ts = fl.slice_range(lo_u, hi_u)
-                    if len(ks):       # flushing arrays are frozen: no copy
-                        parts_k.append(ks)
-                        parts_v.append(vs)
-                        parts_t.append(ts)
-        return self._scan_merge(gen, parts_k, parts_v, parts_t, lo_u, hi_u,
-                                self.stats)
+            gen, runs = self._gen, self._memtable_runs()
+        return self._scan_merge(gen, runs, lo, hi, self.stats)
 
     def _view_scan_iter(self, gen: Generation, mt_keys, mt_vals, mt_tombs,
                         lo: int, hi: int, page_size: int, stats: StoreStats):
@@ -1413,23 +1449,18 @@ class LsmStore:
         run) + SSTables: each key counts by its newest record, and a
         newest-record tombstone means gone."""
         with self._mu:
-            parts_k = [self._mt_keys]
-            parts_t = [self._mt_tombs.copy()]
-            if self._fl_keys is not None:
-                parts_k.append(self._fl_keys)
-                parts_t.append(self._fl_tombs)
+            runs = self._memtable_runs()
             tables = list(self.sstables)
         # a record may transiently sit in BOTH the flushing slot and the
         # newest table (publish installed, slot not yet cleared) — the
         # newest-wins unique below double-counts nothing
-        parts_k += [t.keys for t in tables]
-        parts_t += [
+        parts_k = [r[0] for r in runs] + [t.keys for t in tables]
+        parts_t = [r[2] for r in runs] + [
             t.tombs if t.tombs is not None else np.zeros(len(t.keys), bool)
             for t in tables]
-        cat_k = np.concatenate(parts_k)
-        if not len(cat_k):
+        if not parts_k:
             return 0
-        uk, first_idx = np.unique(cat_k, return_index=True)
+        uk, first_idx = np.unique(np.concatenate(parts_k), return_index=True)
         return int((~np.concatenate(parts_t)[first_idx]).sum())
 
     @property
@@ -1445,8 +1476,7 @@ class LsmStore:
         stall waiters and whether a deferred-GC sweep is owed."""
         with self._mu:
             tables = list(self.sstables)
-            fl = 0 if self._fl_keys is None else len(self._fl_keys)
-            depth = len(self._mt_keys) + fl
+            depth = self._queue_depth()
             waiters = self._stall_waiters
             gc_pending = self._gc_pending
         run = self._find_run(tables)

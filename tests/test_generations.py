@@ -180,9 +180,9 @@ def test_no_generation_leak_after_open_close_cycles():
 
 
 def test_snapshot_sees_memtable_image_at_open():
-    """The snapshot's memtable image is a frozen COPY: later puts/deletes
-    (including in-place big-memtable merges) and the flush that drains the
-    memtable are invisible to it."""
+    """The snapshot's memtable image is frozen: later puts/deletes
+    (including big-memtable splices and folds) and the flush that drains
+    the memtable are invisible to it."""
     store = _store(seed=5)
     a = np.sort(KEYS[:300])
     store.put_batch(a, a + np.uint64(1))      # stays in the memtable

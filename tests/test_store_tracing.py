@@ -13,6 +13,7 @@ import pytest
 
 from repro import trace
 from repro.storage import LsmStore
+from repro.storage.lsm_store import DELTA_ROWS
 
 CAP = 2048
 READ_PHASES = ("get_mu_wait_ns", "overlay_ns", "probe_split_ns",
@@ -140,9 +141,23 @@ def test_build_counters(loaded):
 
 
 def test_splice_counts_the_memtable_rows_it_copies():
+    """A small batch into a big memtable copies only the delta run under
+    the small lock: the rows spliced a put stay below ``DELTA_ROWS`` while
+    the memtable grows past three times that, and folds carry the rest."""
     store = LsmStore(filter_kind="none", memtable_capacity=10**9)
     keys = np.arange(1, 40_001, 2, dtype=np.uint64)
     store.put_batch(keys, keys)
-    store.put_batch(keys[:64] + np.uint64(1), keys[:64])
-    assert store.stats.memtable_rows_spliced == len(keys)
-    assert store.stats.put_calls == 2
+    assert store.stats.memtable_rows_spliced == 0      # bulk: into the base
+    fresh = np.arange(40_001, 40_001 + 2 * 200 * 128, 2, dtype=np.uint64)
+    for batch in fresh.reshape(200, 128):
+        before = store.stats.memtable_rows_spliced
+        store.put_batch(batch, batch)
+        assert store.stats.memtable_rows_spliced - before < DELTA_ROWS
+    s = store.stats
+    assert store.memtable_len == len(keys) + len(fresh) > 2 * DELTA_ROWS
+    assert s.put_calls == 201
+    # a splice of the whole memtable would copy it on every put
+    whole = sum(len(keys) + 128 * i for i in range(200))
+    assert 0 < s.memtable_rows_spliced < whole // 4
+    assert s.memtable_folds == len(fresh) // DELTA_ROWS
+    assert s.fold_rows > len(keys) and s.fold_ns > 0
